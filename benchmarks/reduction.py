@@ -1,9 +1,9 @@
 """Paper Eq. 17 + §3.2.2 reduction analysis: dot products and fused duals.
 
-Measures the host cost of the CG reductions (separate vs fused dual-dot vs
-the Pallas fused kernel) and evaluates the paper's latency models against
-the distributed-computing numbers it cites (MVAPICH 15–35 µs at 1024 nodes,
-GPU >100 µs).
+Measures the host cost of the CG reductions (separate vs fused dual-dot,
+the form ``repro.kernels.ops.dual_dot`` takes) and evaluates the paper's
+latency models against the distributed-computing numbers it cites
+(MVAPICH 15–35 µs at 1024 nodes, GPU >100 µs).
 """
 from __future__ import annotations
 
@@ -13,7 +13,6 @@ import numpy as np
 
 from benchmarks.common import emit, time_fn
 from repro.core.perfmodel import (TPU_V5E_ICI_LAT, wse_dot_time)
-from repro.kernels import ops
 
 
 def run() -> None:
@@ -30,9 +29,6 @@ def run() -> None:
         [jnp.sum(a * b), jnp.sum(c * d)]))
     usf = time_fn(fused, a, b, c, d)
     emit("dot_fused_dual", usf, f"speedup_vs_separate={us2 / usf:.2f}")
-
-    usk = time_fn(lambda *xs: ops.dual_dot(*xs), a, b, c, d)
-    emit("dot_pallas_dual(interpret)", usk, "validated_vs_ref=tests")
 
     # Eq. 17: the paper's 3.25 µs full-fabric dot vs distributed baselines
     t = wse_dot_time(1000, 750, 950) * 1e6

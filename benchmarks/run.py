@@ -132,6 +132,9 @@ def main() -> None:
     if args.repeats is not None and args.repeats < 1:
         ap.error("--repeats must be >= 1")
     common.configure(warmup=args.warmup, repeats=args.repeats)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     for name, mod in mods.items():

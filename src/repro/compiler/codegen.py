@@ -4,7 +4,7 @@
 ``step(env) -> env`` function around exactly one fused ``pl.pallas_call``
 (built by :func:`repro.kernels.fused.build_fused_call`).  Kernels are
 memoized by *program signature* — the lowered tap form plus field
-shapes/dtypes and block/interpret settings — so re-making an identical
+shapes/dtypes and interpret setting — so re-making an identical
 program (the WFA's repeated ``make_WSE`` workflow) reuses the compiled
 kernel; :data:`stats` exposes build/hit/fallback counters for tests and
 benchmarks.
@@ -102,11 +102,11 @@ def _field_specs(group: LoweredGroup, shapes: Dict[str, tuple],
     return specs, base_xy
 
 
-def _get_kernel(group: LoweredGroup, specs, bx, by, nx, ny, block, interpret,
+def _get_kernel(group: LoweredGroup, specs, bx, by, nx, ny, interpret,
                 time_tile, wrap, margin=0, batch=1, region=None):
     from repro.kernels.fused import build_fused_call
     sig = (group, tuple((n, s[0], jnp.dtype(s[1]).name) for n, s in
-                        specs.items()), bx, by, nx, ny, tuple(block),
+                        specs.items()), bx, by, nx, ny,
            bool(interpret), int(time_tile), bool(wrap), int(margin),
            int(batch), region)
     hit = _KERNEL_CACHE.get(sig)
@@ -120,7 +120,7 @@ def _get_kernel(group: LoweredGroup, specs, bx, by, nx, ny, block, interpret,
     # per plan signature" stays truthful for batched plans.  ``region`` tags
     # the overlap scheduler's windowed interior launch (None = whole brick).
     kernel = build_fused_call(group.updates, specs, group.halo, bx, by,
-                              nx, ny, block=block, interpret=interpret,
+                              nx, ny, interpret=interpret,
                               time_tile=time_tile, wrap=wrap, margin=margin,
                               region=region)
     stats.kernels_built += 1
@@ -159,7 +159,7 @@ def compile_transfer(kind: str, fine_shape, coarse_shape, dtype,
     return kernel
 
 
-def _build_overlap_step(group, specs, bx, by, nx, ny, block, interpret,
+def _build_overlap_step(group, specs, bx, by, nx, ny, interpret,
                         time_tile, wrap, margin, batch, split,
                         coords_fn, slabs_fn):
     """One interior/boundary-split step for the exchange/compute overlap.
@@ -189,12 +189,12 @@ def _build_overlap_step(group, specs, bx, by, nx, ny, block, interpret,
 
     ph = time_tile * group.halo
     in_names = list(specs)
-    interior, written = _get_kernel(group, specs, bx, by, nx, ny, block,
+    interior, written = _get_kernel(group, specs, bx, by, nx, ny,
                                     interpret, time_tile, wrap,
                                     margin=margin, batch=batch,
                                     region=split.interior)
     shells = [
-        _get_kernel(group, specs, r.rx, r.ry, nx, ny, block, interpret,
+        _get_kernel(group, specs, r.rx, r.ry, nx, ny, interpret,
                     time_tile, wrap, margin=0, batch=batch)[0]
         for r in split.shells
     ]
@@ -237,7 +237,7 @@ def _build_overlap_step(group, specs, bx, by, nx, ny, block, interpret,
 
 
 def compile_group(ops, shapes: Dict[str, tuple], dtypes: Dict[str, object],
-                  block=(8, 128), interpret: bool = False, *,
+                  interpret: bool = False, *,
                   time_tile: int = 1, group: LoweredGroup = None,
                   resident: int = 0, batch: int = 1, overlap: bool = False):
     """Lower + codegen one loop body for single-device execution.
@@ -293,13 +293,13 @@ def compile_group(ops, shapes: Dict[str, tuple], dtypes: Dict[str, object],
 
             coords0 = jnp.zeros((1, 2), jnp.int32)
             step = _build_overlap_step(
-                group, specs, nx, ny, nx, ny, block, interpret, time_tile,
+                group, specs, nx, ny, nx, ny, interpret, time_tile,
                 True, resident, batch, split,
                 coords_fn=lambda: coords0,
                 slabs_fn=lambda buf: wrap_slabs(buf, resident, ph))
             stats.groups_fused += 1
             return step
-    fused, written = _get_kernel(group, specs, nx, ny, nx, ny, block,
+    fused, written = _get_kernel(group, specs, nx, ny, nx, ny,
                                  interpret, time_tile, wrap=True,
                                  margin=resident, batch=batch)
     in_names = list(specs)
@@ -351,7 +351,7 @@ def compile_group(ops, shapes: Dict[str, tuple], dtypes: Dict[str, object],
 
 def compile_group_sharded(ops, shapes: Dict[str, tuple],
                           dtypes: Dict[str, object], *, mesh_xy, axis_names,
-                          block=(8, 128), interpret: bool = False,
+                          interpret: bool = False,
                           time_tile: int = 1, group: LoweredGroup = None,
                           resident: int = 0, batch: int = 1,
                           overlap: bool = False):
@@ -402,14 +402,14 @@ def compile_group_sharded(ops, shapes: Dict[str, tuple],
         split = split_regions(group, time_tile, (bx, by))
         if split is not None:
             step = _build_overlap_step(
-                group, specs, bx, by, nx, ny, block, interpret, time_tile,
+                group, specs, bx, by, nx, ny, interpret, time_tile,
                 False, resident, batch, split,
                 coords_fn=_coords,
                 slabs_fn=lambda buf: exchange_slabs(
                     buf, resident, ph, ax_x, ax_y, mx, my))
             stats.groups_fused += 1
             return step
-    fused, written = _get_kernel(group, specs, bx, by, nx, ny, block,
+    fused, written = _get_kernel(group, specs, bx, by, nx, ny,
                                  interpret, time_tile, wrap=False,
                                  margin=resident, batch=batch)
     in_names = list(specs)

@@ -115,7 +115,7 @@ class TiledGroup:
 
 def tile_group(group: LoweredGroup, k: int,
                brick_xy: Tuple[int, int] = None,
-               n_steps: int = None) -> TiledGroup:
+               n_steps: int = None, fields=None) -> TiledGroup:
     """Validate and build the ``k``-step composition of ``group``.
 
     Legality: the body must already be in canonical affine tap form (i.e. a
@@ -124,9 +124,11 @@ def tile_group(group: LoweredGroup, k: int,
     either updated by the body itself (its sub-step evolution is replayed
     in-window) or constant over the tile (a coefficient field).  Bounds:
     the tiled halo ``k·h`` must fit inside the brick (``ppermute`` moves at
-    most one brick per hop) and ``k`` cannot exceed the loop trip count.
-    Violations raise :class:`LoweringError`; the planner falls back to
-    ``k = 1`` with a logged reason.
+    most one brick per hop), ``k`` cannot exceed the loop trip count, and —
+    given the body's ``fields`` (``name -> (nz, dtype)``) — the kernel's
+    depth-``k·h`` window must fit its VMEM (:func:`fused_x_block`).
+    Violations raise :class:`LoweringError`; the planner falls back to a
+    smaller ``k`` with a logged reason.
     """
     if not isinstance(k, int) or k < 1:
         raise LoweringError(f"time tile factor must be a positive int, got {k!r}")
@@ -139,19 +141,41 @@ def tile_group(group: LoweredGroup, k: int,
                 f"time tile k={k} needs halo depth {k * group.halo} > brick "
                 f"extent {min(brick_xy)}; neighbour exchange only reaches one "
                 "brick")
+    if brick_xy is not None and fields is not None:
+        if not fused_x_block(group, k, brick_xy, fields):
+            raise LoweringError(
+                f"time tile k={k}: the fused window of a {brick_xy} brick "
+                "does not fit the kernel's VMEM")
     return TiledGroup(base=group, k=k)
 
 
+def fused_x_block(group: LoweredGroup, k: int, brick_xy: Tuple[int, int],
+                  fields) -> int:
+    """X block the fused kernel takes for this body at tile ``k`` on a
+    ``brick_xy`` brick (0 when not even one row of its window fits VMEM).
+    ``fields`` maps every field the body touches to ``(nz, dtype)``."""
+    from repro.kernels.fused import pick_x_block
+
+    bx, by = brick_xy
+    kh = k * group.halo
+    return pick_x_block(fields, group.fields_written(), group.halo, k, bx,
+                        by + 2 * kh, by)
+
+
 def auto_tile(group: LoweredGroup, brick_xy: Tuple[int, int],
-              n_steps: int, max_k: int = 8, *, cost=None, nz: int = None
-              ) -> int:
+              n_steps: int, max_k: int = 8, *, cost=None, nz: int = None,
+              fields=None) -> int:
     """Pick a time-tile factor.
 
     Without a cost model this is the static rule: the largest power of two
     ``k ≤ max_k`` that divides the trip count (auto-tiled runs never need a
     remainder kernel) and whose tiled halo stays small next to the brick
     (``4·k·h ≤ min(bx, by)``, i.e. at most ~25% linear overhead per side).
-    Halo-free bodies tile purely for launch amortization.
+    Given the body's ``fields`` (``name -> (nz, dtype)``), ``k`` must also
+    leave the kernel a window that fits its VMEM, and the halo must stay as
+    small next to the X block the kernel then loads (``4·k·h ≤ bxb``): the
+    rows of neighbouring windows overlap by ``2·k·h``, and each launch
+    recomputes them.  Halo-free bodies tile purely for launch amortization.
 
     With ``cost=`` (a calibrated :class:`repro.core.perfmodel.MeasuredCost`
     for this body's signature) and ``nz``, the choice is the argmin of the
@@ -161,6 +185,13 @@ def auto_tile(group: LoweredGroup, brick_xy: Tuple[int, int],
     candidate, so a model-driven pick can never lose to untiled stepping by
     construction.
     """
+    def x_block(cand):
+        # X block the kernel loads at this k (0: its window cannot fit);
+        # without the body's fields, the brick's whole X extent
+        if fields is None:
+            return brick_xy[0]
+        return fused_x_block(group, cand, brick_xy, fields)
+
     if cost is not None and nz is not None and n_steps > 1:
         from repro.core.perfmodel import predict_step_us
 
@@ -170,7 +201,8 @@ def auto_tile(group: LoweredGroup, brick_xy: Tuple[int, int],
         while cand <= min(max_k, n_steps):
             legal = (n_steps % cand == 0
                      and (group.halo == 0
-                          or cand * group.halo <= min(brick_xy)))
+                          or cand * group.halo <= min(brick_xy))
+                     and x_block(cand) > 0)
             if legal:
                 t = predict_step_us(cost, brick_xy, nz, group.halo, cand)
                 ts = predict_step_us(cost, brick_xy, nz, group.halo, cand,
@@ -182,10 +214,11 @@ def auto_tile(group: LoweredGroup, brick_xy: Tuple[int, int],
         return best_k
     cand = max_k
     while cand >= 2:
-        if (cand <= n_steps and n_steps % cand == 0
-                and (group.halo == 0
-                     or 4 * cand * group.halo <= min(brick_xy))):
-            return cand
+        if cand <= n_steps and n_steps % cand == 0:
+            bxb = x_block(cand)
+            if bxb and (group.halo == 0
+                        or 4 * cand * group.halo <= min(bxb, brick_xy[1])):
+                return cand
         cand //= 2
     return 1
 

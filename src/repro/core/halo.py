@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import stencil as st
+from repro.core.jaxcompat import make_mesh
 from repro.core.program import Program
 
 
@@ -185,7 +186,7 @@ def default_mesh2d():
     mx = int(np.sqrt(n))
     while n % mx:
         mx -= 1
-    return jax.make_mesh((mx, n // mx), ("data", "model"))
+    return make_mesh((mx, n // mx), ("data", "model"))
 
 
 def run_sharded(program: Program, env: Dict[str, np.ndarray], mesh=None,

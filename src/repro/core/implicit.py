@@ -256,14 +256,7 @@ def make_sharded_iteration(mesh, shape, w: float, *, method: str = "cg",
 
         def dot2(a, b, c, d):
             from repro.kernels import ops as kops
-            # fused dual-dot kernel on Mosaic only: in interpret mode the
-            # extra pallas launch per reduction costs more than it fuses
-            if use_kernel and not kops._interpret():
-                part = kops.dual_dot(a, b, c, d)
-            else:
-                part = jnp.stack([jnp.sum(a * b, dtype=jnp.float32),
-                                  jnp.sum(c * d, dtype=jnp.float32)])
-            part = jax.lax.psum(part, (ax_x, ax_y))
+            part = jax.lax.psum(kops.dual_dot(a, b, c, d), (ax_x, ax_y))
             return part[0], part[1]
 
         if method == "cg":

@@ -1,11 +1,8 @@
-"""JAX version compatibility (0.4.x ↔ 0.5+).
+"""Mesh and ``shard_map`` spellings shared by the sharded layers.
 
-The sharded layers are written against the modern spellings
-(``jax.shard_map(..., check_vma=)``, ``jax.make_mesh(..., axis_types=)``);
-on 0.4.x those live in ``jax.experimental.shard_map`` (``check_rep=``) and
-``axis_types`` does not exist.  Every call site routes through here so the
-same tree runs on both.  (The Pallas analogue lives in
-:mod:`repro.kernels.compat`.)
+``jax.make_mesh`` defaults to explicit-sharding axis types; every mesh this
+package builds uses ``Auto`` axes, which ``shard_map`` bodies and
+``NamedSharding`` placement here assume.
 """
 from __future__ import annotations
 
@@ -13,17 +10,11 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
-def make_mesh(shape, axis_names):
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(shape, axis_names)
+def make_mesh(shape, axis_names, devices=None):
+    return jax.make_mesh(
+        shape, axis_names, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))

@@ -188,8 +188,8 @@ class MeasuredCost:
 def current_device() -> str:
     """Device tag calibration entries are keyed under.
 
-    Forced-interpret runs (``REPRO_FORCE_INTERPRET=1``) time the pallas
-    interpreter, not compiled kernels, so they get a distinct tag — an
+    Off a TPU the pallas kernels run in interpret mode, which times the
+    interpreter, not compiled kernels, so such runs get a distinct tag — an
     interpret-mode manifest can never steer a compiled run.
     """
     import jax

@@ -33,6 +33,15 @@ from repro.engine.options import UNSET, RunOptions, resolve_options
 from repro.engine.stats import stats
 
 log = logging.getLogger("repro.engine")
+_LOGGED = set()
+
+
+def _log_once(msg: str) -> None:
+    """Log a degradation the plan takes on this device once per process;
+    the counters in :data:`repro.engine.stats` count every occurrence."""
+    if msg not in _LOGGED:
+        _LOGGED.add(msg)
+        log.warning("%s", msg)
 
 #: user-facing backends accepted by plan() (``shard_map`` is ``jit`` + mesh)
 BACKENDS = ("numpy", "jit", "shard_map", "pallas")
@@ -227,6 +236,19 @@ class LevelSegment:
     prolong: Optional[Callable] = None
 
 
+def transfer_kernels() -> bool:
+    """Whether multigrid transfers run as Pallas kernels on this device.
+
+    Mosaic restricts the transfer kernels' interleave reshapes (see
+    :mod:`repro.kernels.transfer`), so on a TPU the pallas backend runs the
+    jnp references instead — counted in ``stats.mg_transfer_refs`` and
+    logged once — rather than crashing at first trace.
+    """
+    from repro.kernels.ops import _interpret
+
+    return _interpret()
+
+
 def plan_mg_levels(bodies, backend: str, dtype) -> List[LevelSegment]:
     """Schedule one multigrid hierarchy: every level body through the
     engine's single dispatch point, every transfer through the kernel cache.
@@ -264,13 +286,14 @@ def plan_mg_levels(bodies, backend: str, dtype) -> List[LevelSegment]:
             coarse = tuple(bodies[lvl + 1]["shape"])
             use_kernels = False
             if backend == "pallas":
-                from repro.kernels.ops import _interpret
-
-                # Mosaic restricts the transfer kernels' interleave reshapes
-                # (see kernels/transfer.py); on real TPUs fall back to the
-                # jnp references — the documented degradation path — instead
-                # of crashing at first trace.
-                use_kernels = _interpret()
+                use_kernels = transfer_kernels()
+                if not use_kernels:
+                    stats.mg_transfer_refs += 1
+                    _log_once(
+                        "multigrid transfers run as jnp references: Mosaic "
+                        "cannot compile the transfer kernels' interleave "
+                        "reshapes"
+                    )
             if use_kernels:
                 seg.restrict = compile_transfer(
                     "restrict", shape, coarse, dtype, interpret=True
@@ -304,7 +327,8 @@ def _brick_xy(program: Program, mesh_ctx, group) -> Tuple[int, int]:
 
 
 def _pick_tile(
-    group, loop, requested: Optional[int], brick_xy, cost=None, nz=None
+    group, loop, requested: Optional[int], brick_xy, cost=None, nz=None,
+    fields=None,
 ) -> Tuple[int, str]:
     """Resolve the tile factor for one fused loop body: (k, clamp_reason).
 
@@ -312,22 +336,27 @@ def _pick_tile(
     MeasuredCost` entry when one exists: auto selection then minimizes the
     measured model over the legal candidates instead of applying the static
     rule (``k = 1`` always admissible, so tiling cannot lose by
-    construction — see :func:`repro.compiler.ir.auto_tile`).
+    construction — see :func:`repro.compiler.ir.auto_tile`).  ``fields``
+    (``name -> (nz, dtype)``) lets both paths bound the kernel's window by
+    its VMEM.
     """
     n = loop.n if loop is not None else 1
     if n <= 1:
         return 1, ""
     if requested is None:
-        return auto_tile(group, brick_xy, n, cost=cost, nz=nz), ""
+        return auto_tile(group, brick_xy, n, cost=cost, nz=nz, fields=fields), ""
     k = max(1, int(requested))
     try:
-        tile_group(group, k, brick_xy=brick_xy, n_steps=n)
+        tile_group(group, k, brick_xy=brick_xy, n_steps=n, fields=fields)
         return k, ""
     except LoweringError as e:
-        kmax = n
-        if group.halo > 0:
-            kmax = min(kmax, min(brick_xy) // group.halo)
-        k_ok = max(1, min(k, kmax))
+        k_ok = min(k, n)
+        while k_ok > 1:
+            try:
+                tile_group(group, k_ok, brick_xy=brick_xy, fields=fields)
+                break
+            except LoweringError:
+                k_ok -= 1
         reason = f"time_tile={requested} clamped to k={k_ok}: {e}"
         log.warning("%s", reason)
         return k_ok, reason
@@ -420,6 +449,7 @@ def plan(
                 )
                 if cost is not None:
                     stats.cost_model_hits += 1
+                names = dict.fromkeys(group.fields_written() + group.fields_read())
                 k, reason = _pick_tile(
                     group,
                     loop,
@@ -427,6 +457,7 @@ def plan(
                     _brick_xy(program, mesh_ctx, group),
                     cost=cost,
                     nz=shapes[name0][2],
+                    fields={n: (shapes[n][2], dtypes[n]) for n in names},
                 )
         elif backend != "numpy" and time_tile is not None and time_tile != 1:
             # an explicit tile request on an interpreter backend is dropped,
@@ -454,10 +485,16 @@ def plan(
         # path double-buffers block outputs on TPU, Mosaic plans keep the
         # legacy repacking steps — the same documented degradation rule as
         # the multigrid transfer kernels (engine.plan_mg_levels).
-        if _interpret():
-            pad = max(
-                (k * g.halo for _, _, g, k, _, _ in scheduled if g is not None),
-                default=0,
+        pad = max(
+            (k * g.halo for _, _, g, k, _, _ in scheduled if g is not None),
+            default=0,
+        )
+        if pad and not _interpret():
+            pad = 0
+            stats.resident_dropped += 1
+            _log_once(
+                "halo-resident layout off on Mosaic: fused launches repack "
+                "their inputs (and run no overlap split)"
             )
     layout = HaloLayout(pad=pad, shapes=shapes)
 

@@ -47,6 +47,9 @@ class EngineStats:
     #: path; on a resident run only the layout enter/exit events (2 for an
     #: all-fused plan, +2 around each interpreter segment in a mixed plan)
     repacks: int = 0
+    #: plans that kept the repacking steps because Mosaic cannot run the
+    #: in-place halo-resident layout (see :func:`repro.engine.plan`)
+    resident_dropped: int = 0
     max_time_tile: int = 1  # largest k any segment ran with
     elapsed_s: float = 0.0  # wall time inside execute()
     tile_reasons: Tuple[str, ...] = ()  # why a tile factor was clamped/refused
@@ -63,6 +66,9 @@ class EngineStats:
     mg_levels_built: int = 0  # level segments compiled across hierarchies
     #: (shape, smoother-fused, residual-fused) per level of the last hierarchy
     mg_level_log: Tuple[Tuple[Tuple[int, int, int], bool, bool], ...] = ()
+    #: level pairs whose restriction/prolongation run as the jnp references
+    #: because Mosaic cannot compile the transfer kernels
+    mg_transfer_refs: int = 0
 
     # -- batched ensembles (plans with options.batch > 1) -------------------
     ensemble_runs: int = 0  # executes of a batched plan (one launch, B members)
@@ -113,47 +119,8 @@ stats = EngineStats()
 
 def reset_stats() -> None:
     # mutate in place so `from repro.engine import stats` stays live
-    stats.plans_built = 0
-    stats.bodies_compiled = 0
-    stats.segments_fused = 0
-    stats.segments_interp = 0
-    stats.steps_run = 0
-    stats.launches = 0
-    stats.exchanges = 0
-    stats.tiles_fused = 0
-    stats.resident_runs = 0
-    stats.repacks = 0
-    stats.max_time_tile = 1
-    stats.elapsed_s = 0.0
-    stats.tile_reasons = ()
-    stats.interior_launches = 0
-    stats.boundary_launches = 0
-    stats.overlapped_exchanges = 0
-    stats.cost_model_hits = 0
-    stats.calibrations = 0
-    stats.mg_hierarchies = 0
-    stats.mg_levels_built = 0
-    stats.mg_level_log = ()
-    stats.ensemble_runs = 0
-    stats.ensemble_members = 0
-    stats.member_iterations = ()
-    stats.health_probes = 0
-    stats.numerical_faults = 0
-    stats.recovery_attempts = 0
-    stats.solve_outcomes = ()
-    stats.requests_admitted = 0
-    stats.requests_rejected = 0
-    stats.requests_expired = 0
-    stats.requests_completed = 0
-    stats.requests_failed = 0
-    stats.requests_degraded = 0
-    stats.request_retries = 0
-    stats.plan_builds = 0
-    stats.plan_cache_hits = 0
-    stats.service_checkpoints = 0
-    stats.service_restores = 0
-    stats.service_stragglers = 0
-    stats.queue_wait_s = 0.0
+    for f in dataclasses.fields(EngineStats):
+        setattr(stats, f.name, f.default)
 
 
 def service_stats() -> dict:

@@ -1,11 +1,9 @@
-"""Pallas API version shims.
+"""Element-offset block specs for overlapping stencil windows.
 
-Overlapping stencil windows need *element*-offset indexing: the index map
-returns cell offsets, not block indices, so neighbouring grid blocks may read
-overlapping (halo) rows.  jax ≥ 0.5 spells this ``pl.Element(n, padding=…)``
-per dimension; jax 0.4.x (this container) spells it
-``indexing_mode=pl.Unblocked(padding)`` on the whole BlockSpec.  The kernels
-go through this helper so both spellings work.
+The index map of such a spec returns cell offsets, not block indices, so
+neighbouring grid blocks may read overlapping (halo) rows.  Pallas takes
+element indexing on every dimension of a block or on none, so the helper
+wraps them all.
 """
 from __future__ import annotations
 
@@ -17,14 +15,9 @@ from jax.experimental import pallas as pl
 def element_block_spec(block_shape: Sequence[int], index_map: Callable,
                        padding: Optional[Sequence[Tuple[int, int]]] = None):
     """BlockSpec with element-offset indexing + optional (lo, hi) zero pads."""
-    if hasattr(pl, "Element"):
-        if padding is None:
-            padding = [(0, 0)] * len(block_shape)
-        dims = tuple(
-            pl.Element(n, padding=tuple(p)) if tuple(p) != (0, 0)
-            else pl.Element(n)
-            for n, p in zip(block_shape, padding))
-        return pl.BlockSpec(dims, index_map)
-    mode = (pl.unblocked if padding is None
-            else pl.Unblocked(tuple(tuple(p) for p in padding)))
-    return pl.BlockSpec(tuple(block_shape), index_map, indexing_mode=mode)
+    if padding is None:
+        padding = [(0, 0)] * len(block_shape)
+    dims = tuple(
+        pl.Element(n, padding=tuple(p)) if tuple(p) != (0, 0) else pl.Element(n)
+        for n, p in zip(block_shape, padding))
+    return pl.BlockSpec(dims, index_map)

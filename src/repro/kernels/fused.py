@@ -9,11 +9,18 @@ body.  Sequential updates inside one body see earlier updates' *local*
 values (dx = dy = 0 reads only — the lowering pass rejects the rest),
 mirroring the Control Tile's ordered RPC stream.
 
-Time tiling (``time_tile=k``): each grid cell loads one overlapping
-``(bxb + 2kh, byb + 2kh, Z)`` window per input field (``pl.Element``
-indexing) and applies the loop body ``k`` times in VMEM, the valid region
-shrinking by ``h`` per sub-step (trapezoid blocking), so the caller pays the
-halo exchange / wrap pad once per *tile* instead of once per step.  The
+Layout: fields are (X, Y, Z) with Z on the 128 lanes and Y on the 8
+sublanes of a vreg.  Mosaic takes a block's Y extent only as a multiple of 8
+or as the array's whole extent, so the grid blocks X alone: each grid step
+loads, per input field, an overlapping ``(bxb + 2kh, Y, Z)`` window that
+spans the whole padded Y extent (element indexing on X), and ``bxb`` is the
+largest X block whose double-buffered windows and body temporaries fit the
+kernel's VMEM (:func:`pick_x_block`).
+
+Time tiling (``time_tile=k``): each window is stepped ``k`` times in VMEM,
+the valid region shrinking by ``h`` per sub-step (trapezoid blocking), so
+the caller pays the halo exchange / wrap pad once per *tile* instead of
+once per step.  The
 Dirichlet Moat mask is applied per sub-step from global coordinates — with
 ``wrap=True`` (single device, ``jnp.pad(mode="wrap")`` margins) coordinates
 are taken modulo the grid so halo cells evolve exactly like the domain cells
@@ -42,32 +49,89 @@ from typing import Dict, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.compiler.ir import LoweringError
 from repro.kernels.compat import element_block_spec
-from repro.kernels.stencil7 import _pick_block
 
 
-def _read_tap(tap, u, cur, center, h, out_x, out_y):
-    """Value of one tap over the update's target block, (out_x, out_y, zlen)."""
-    zlo = u.z0 + tap.dz
-    if tap.field in center:
-        # field already updated this sub-step: lowering guarantees
-        # dx == dy == 0, so the read is block-local (already out-sized).
-        return center[tap.field][:, :, zlo:zlo + u.zlen]
-    a = cur[tap.field]
-    x0 = h + tap.dx
-    y0 = h + tap.dy
-    return a[x0:x0 + out_x, y0:y0 + out_y, zlo:zlo + u.zlen]
+#: scoped VMEM one fused launch may use.  A v5e core holds 128 MiB of VMEM
+#: and Mosaic scopes 16 MiB by default; the kernel sets its own limit to
+#: this and sizes its X block (and the planner its time tile) to fit it.
+#: Larger blocks would recompute less halo but compile slower: Mosaic
+#: unrolls the body over every vreg of every sub-step.
+VMEM_LIMIT = 32 * 2**20
+#: largest X block a launch takes (rows of full-Y windows)
+_X_BLOCK_MAX = 32
+#: body temporaries per field, in windows: the loaded window, its z-rolled
+#: taps and the accumulators (the v5e compiler's scoped-VMEM need for the
+#: 7-point heat body at 512x512x128 puts them at 1.0 window at k=1 and 2.6
+#: at k=4)
+_TEMPS = 3
 
 
-def _apply_updates(updates, cur, nz_of, h, out_x, out_y, gx0, gy0, nx, ny,
-                   wrap):
+def _tile_bytes(rows: int, ys: int, nz: int, itemsize: int) -> int:
+    """Bytes of a (rows, ys, nz) VMEM value: Y pads to 8 sublanes, Z to
+    whole 128-lane vregs."""
+    return rows * (-(-ys // 8) * 8) * (-(-nz // 128) * 128) * itemsize
+
+
+def window_vmem_bytes(field_specs: Dict[str, Tuple[int, object]],
+                      written: Sequence[str], halo: int, k: int, bxb: int,
+                      ya: int, ry: int) -> int:
+    """Upper bound on the VMEM one grid step needs, from shapes alone.
+
+    Every input window ``(bxb + 2kh, ya, nz)`` and output block ``(bxb, ya,
+    nz)`` is double-buffered by the Pallas pipeline, and the body keeps up
+    to :data:`_TEMPS` more region-sized windows live per field.
+    """
+    rows = bxb + 2 * k * halo
+    total = 0
+    for name, (nz, dtype) in field_specs.items():
+        isz = jnp.dtype(dtype).itemsize
+        total += 2 * _tile_bytes(rows, ya, nz, isz)
+        total += _TEMPS * _tile_bytes(rows, ry + 2 * k * halo, nz, isz)
+        if name in written:
+            total += 2 * _tile_bytes(bxb, ya, nz, isz)
+    return total
+
+
+def pick_x_block(field_specs, written, halo: int, k: int, rx: int, ya: int,
+                 ry: int) -> int:
+    """Largest divisor of ``rx`` (≤ ``_X_BLOCK_MAX``) whose grid step fits
+    :data:`VMEM_LIMIT`, or 0 when not even one row does."""
+    for b in range(min(rx, _X_BLOCK_MAX), 0, -1):
+        if rx % b == 0 and window_vmem_bytes(field_specs, written, halo, k, b,
+                                             ya, ry) <= VMEM_LIMIT:
+            return b
+    return 0
+
+
+def _roll_z(a, dz):
+    """``a`` shifted so lane ``z`` holds ``a[..., z + dz]`` (wrapping)."""
+    if dz == 0:
+        return a
+    return pltpu.roll(a, (-dz) % a.shape[-1], a.ndim - 1)
+
+
+def _apply_updates(updates, cur, h, out_x, out_y, gx0, gy0, nx, ny, wrap,
+                   interpret):
     """One sub-step: apply every update over the (out_x, out_y) region.
 
     ``cur`` holds full-Z arrays of extent (out_x + 2h, out_y + 2h); returns
     the post-step dict shrunk to (out_x, out_y).  ``gx0, gy0`` are the global
     coordinates of the *output* region's origin; with ``wrap`` they are taken
     modulo the grid so wrap-pad margin cells mask like the cells they mirror.
+
+    Z (lanes) is handled two ways with the same per-cell arithmetic.  On
+    Mosaic every value spans the whole Z extent: a z-shifted tap is a lane
+    rotation of its field and an update's target window ``[z0, z0+zlen)``
+    is a lane mask on the final select (Mosaic has no lowering for a
+    dynamic-update-slice splice).  Under the interpreter the taps read the
+    window itself and the result is spliced back in place: the splice keeps
+    XLA from fusing the update into whatever consumes the kernel's output,
+    which would change FMA contraction and make a result depend on the
+    program around the kernel.
     """
     row = jax.lax.broadcasted_iota(jnp.int32, (out_x, out_y, 1), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (out_x, out_y, 1), 1)
@@ -79,22 +143,42 @@ def _apply_updates(updates, cur, nz_of, h, out_x, out_y, gx0, gy0, nx, ny,
     interior = (gx > 0) & (gx < nx - 1) & (gy > 0) & (gy < ny - 1)
 
     center: Dict[str, jnp.ndarray] = {}   # full-Z out-sized blocks, updated
+    rolled: Dict[tuple, jnp.ndarray] = {}  # Mosaic: lane-rotated sources
+
+    def read(tap, z):
+        # a field already updated this sub-step is read block-locally
+        # (lowering guarantees dx == dy == 0 there); others through the halo
+        local = tap.field in center
+        src = center[tap.field] if local else cur[tap.field]
+        if z is not None:
+            src = src[:, :, z.start + tap.dz:z.stop + tap.dz]
+        else:
+            key = (tap.field, tap.dz, local)
+            if key not in rolled:
+                rolled[key] = _roll_z(src, tap.dz)
+            src = rolled[key]
+        if local:
+            return src
+        return src[h + tap.dx:h + tap.dx + out_x, h + tap.dy:h + tap.dy + out_y]
+
     for u in updates:
-        nz = nz_of[u.field]
         if u.field in center:
             old = center[u.field]
         else:
             old = cur[u.field][h:h + out_x, h:h + out_y, :]
+        nz = old.shape[-1]
         dtype = old.dtype
+        whole = (u.z0, u.zlen) == (0, nz)
+        z = None if whole or not interpret else slice(u.z0, u.z0 + u.zlen)
         # group products sharing a scalar coefficient: sum first, multiply
         # once — fewer VPU multiplies and the same association the source
         # spelling `c * (T_E + T_W + ...)` used, so rounding matches the
         # interpreter to ~1 ulp.
         groups: Dict[float, jnp.ndarray] = {}
         for coeff, taps in u.terms:
-            t = _read_tap(taps[0], u, cur, center, h, out_x, out_y)
+            t = read(taps[0], z)
             for tap in taps[1:]:
-                t = t * _read_tap(tap, u, cur, center, h, out_x, out_y)
+                t = t * read(tap, z)
             groups[coeff] = t if coeff not in groups else groups[coeff] + t
         acc = None
         for coeff, t in groups.items():
@@ -102,22 +186,22 @@ def _apply_updates(updates, cur, nz_of, h, out_x, out_y, gx0, gy0, nx, ny,
                 t = dtype.type(coeff) * t
             acc = t if acc is None else acc + t
         if acc is None:
-            acc = jnp.full((out_x, out_y, u.zlen), u.const, dtype)
+            acc = jnp.full((out_x, out_y, nz if z is None else u.zlen),
+                           u.const, dtype)
         elif u.const != 0.0:
             acc = acc + dtype.type(u.const)
 
-        old_z = old[:, :, u.z0:u.z0 + u.zlen]
-        new_z = jnp.where(interior, acc, old_z)
-        # splice the updated z window in place: dynamic_update_slice (same
-        # values as concatenating the flanking slices) keeps the per-sub-step
-        # splice fusible, where a concatenate chain re-materializes the whole
-        # block each sub-step — the difference between time tiles costing
-        # ~k× one launch and costing ~1× (see docs/time_tiling.md).
-        if u.z0 == 0 and u.zlen == nz:
-            center[u.field] = new_z
-        else:
+        if whole:
+            center[u.field] = jnp.where(interior, acc, old)
+        elif z is not None:
             center[u.field] = jax.lax.dynamic_update_slice(
-                old, new_z, (0, 0, u.z0))
+                old, jnp.where(interior, acc, old[:, :, z]), (0, 0, u.z0))
+        else:
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, nz), 2)
+            keep = interior & (lane >= u.z0) & (lane < u.z0 + u.zlen)
+            center[u.field] = jnp.where(keep, acc, old)
+        # later reads of this field see the new values
+        rolled = {key: v for key, v in rolled.items() if key[0] != u.field}
 
     out = {}
     for name, a in cur.items():
@@ -126,29 +210,38 @@ def _apply_updates(updates, cur, nz_of, h, out_x, out_y, gx0, gy0, nx, ny,
     return out
 
 
-def _fused_body(updates, in_names, written, nz_of, h, k, wrap, bxb, byb,
-                nx, ny, coords_ref, *refs):
-    cur = dict(zip(in_names, (r[...] for r in refs[:len(in_names)])))
+def _fused_body(updates, in_names, written, h, k, wrap, bxb, ry, y_lo, nx,
+                ny, margin, interpret, coords_ref, *refs):
+    kh = k * h
+    in_refs = dict(zip(in_names, refs[:len(in_names)]))
     out_refs = dict(zip(written, refs[len(in_names):]))
+    # the loaded windows span the array's whole Y extent; the body steps
+    # the ry output rows of its region (plus their depth-kh halo)
+    cur = {n: r[:, y_lo:y_lo + ry + 2 * kh, :] for n, r in in_refs.items()}
     i = pl.program_id(0)
-    j = pl.program_id(1)
     # global origin of the loaded window (halo depth k·h below the block)
-    gx0 = coords_ref[0, 0] + i * bxb - k * h
-    gy0 = coords_ref[0, 1] + j * byb - k * h
+    gx0 = coords_ref[0, 0] + i * bxb - kh
+    gy0 = coords_ref[0, 1] - kh
     for s in range(k):
         out_x = bxb + 2 * (k - s - 1) * h
-        out_y = byb + 2 * (k - s - 1) * h
+        out_y = ry + 2 * (k - s - 1) * h
         gx0 = gx0 + h   # origin of this sub-step's output region
         gy0 = gy0 + h
-        cur = _apply_updates(updates, cur, nz_of, h, out_x, out_y, gx0, gy0,
-                             nx, ny, wrap)
+        cur = _apply_updates(updates, cur, h, out_x, out_y, gx0, gy0,
+                             nx, ny, wrap, interpret)
     for name in written:
-        out_refs[name][...] = cur[name]
+        if margin:
+            # the output block is a whole resident row: cells outside the
+            # region keep their pre-launch values
+            out_refs[name][...] = in_refs[name][kh:kh + bxb, :, :]
+            out_refs[name][:, y_lo + kh:y_lo + kh + ry, :] = cur[name]
+        else:
+            out_refs[name][...] = cur[name]
 
 
 def build_fused_call(updates: Sequence, field_specs: Dict[str, Tuple[int, object]],
                      halo: int, bx: int, by: int, nx: int, ny: int,
-                     block=(8, 128), interpret: bool = False,
+                     interpret: bool = False,
                      time_tile: int = 1, wrap: bool = False,
                      margin: int = 0, region=None):
     """Build the fused kernel for one loop body.
@@ -203,40 +296,56 @@ def build_fused_call(updates: Sequence, field_specs: Dict[str, Tuple[int, object
     # output blocks shift by the region origin inside the resident buffer
     rx, ry = (bx, by) if region is None else (region.rx, region.ry)
     ox, oy = (0, 0) if region is None else (region.x0, region.y0)
-    bxb = _pick_block(rx, block[0])
-    byb = _pick_block(ry, block[1])
-    grid = (rx // bxb, ry // byb)
+    kh = k * h
+    # Mosaic takes a block's second-minor (Y, sublane) extent only as a
+    # multiple of 8 or as the whole array extent; a depth-kh halo window
+    # is neither, so every window and output block spans the array's
+    # whole Y extent and the grid blocks X (the untiled leading axis) only
+    ya = by + 2 * (margin if margin else kh)
+    bxb = pick_x_block(field_specs, written, h, k, rx, ya, ry)
+    if not bxb:
+        raise LoweringError(
+            f"fused window ({1 + 2 * kh}, {ya}, nz) at k={k} does not fit "
+            f"{VMEM_LIMIT >> 20} MiB of VMEM")
+    if interpret:
+        # the interpreter runs the grid as an XLA loop; blocks of at most 8
+        # rows keep that loop, and with it the kernel's arithmetic, apart
+        # from the caller's fusions — a one-step grid would let XLA fuse the
+        # kernel into its consumer and change FMA contraction, so a result
+        # would depend on the program around the kernel
+        bxb = max(b for b in range(1, min(bxb, 8) + 1) if rx % b == 0)
+    grid = (rx // bxb,)
+    # Y offset of the region's depth-kh window inside the loaded window
+    y_lo = margin - kh + oy if margin else 0
 
     body = functools.partial(_fused_body, tuple(updates), tuple(in_names),
-                             tuple(written), nz_of, h, k, wrap, bxb, byb,
-                             nx, ny)
+                             tuple(written), h, k, wrap, bxb, ry, y_lo,
+                             nx, ny, margin, interpret)
     # window origin inside the input: the kernel always consumes a
-    # (bxb + 2kh, byb + 2kh) window; with a resident margin that window sits
-    # `margin - kh` cells inside the buffer edge (legacy inputs arrive
+    # (bxb + 2kh)-row window; with a resident margin that window sits
+    # `margin - kh` rows inside the buffer edge (legacy inputs arrive
     # already window-aligned — their whole extent IS the padded window).
-    off_x = margin - k * h + ox if margin else 0
-    off_y = margin - k * h + oy if margin else 0
-    in_specs = [pl.BlockSpec((1, 2), lambda i, j: (0, 0))]
+    off_x = margin - kh + ox if margin else 0
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]
     for name in in_names:
-        nz = nz_of[name]
         in_specs.append(element_block_spec(
-            (bxb + 2 * k * h, byb + 2 * k * h, nz),
-            lambda i, j, ax=off_x, ay=off_y: (ax + i * bxb, ay + j * byb, 0)))
+            (bxb + 2 * kh, ya, nz_of[name]),
+            lambda i, ax=off_x: (ax + i * bxb, 0, 0)))
     if margin:
         # in-place outputs: each written field aliases its own input buffer
-        # (full resident extent); the grid writes only the region's blocks,
-        # margins (and, in region mode, the rest of the brick) keep their
-        # pre-launch values.
+        # (full resident extent); the grid writes only the region's rows,
+        # and the body copies the pre-launch values of every cell it does
+        # not update, so margins (and, in region mode, the rest of the
+        # brick) keep their values.
         out_specs = [element_block_spec(
-            (bxb, byb, nz_of[n]),
-            lambda i, j: (margin + ox + i * bxb, margin + oy + j * byb, 0))
-            for n in written]
+            (bxb, ya, nz_of[n]),
+            lambda i: (margin + ox + i * bxb, 0, 0)) for n in written]
         out_shape = [jax.ShapeDtypeStruct(
             (bx + 2 * margin, by + 2 * margin, nz_of[n]), field_specs[n][1])
             for n in written]
         aliases = {1 + in_names.index(n): o for o, n in enumerate(written)}
     else:
-        out_specs = [pl.BlockSpec((bxb, byb, nz_of[n]), lambda i, j: (i, j, 0))
+        out_specs = [pl.BlockSpec((bxb, by, nz_of[n]), lambda i: (i, 0, 0))
                      for n in written]
         out_shape = [jax.ShapeDtypeStruct((bx, by, nz_of[n]), field_specs[n][1])
                      for n in written]
@@ -250,6 +359,8 @@ def build_fused_call(updates: Sequence, field_specs: Dict[str, Tuple[int, object
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=VMEM_LIMIT),
     )
 
     def fused(coords, *padded):

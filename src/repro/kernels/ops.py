@@ -1,25 +1,19 @@
 """Jitted public wrappers around the Pallas kernels.
 
-On a TPU backend the kernels compile via Mosaic; on CPU (this container, and
-any unit-test environment) they execute under ``interpret=True`` so the same
-call sites work everywhere.  Set ``REPRO_FORCE_INTERPRET=0`` to override.
+On a TPU backend the kernels compile via Mosaic; on any other backend (the
+CPU unit tests) they execute under ``interpret=True`` so the same call sites
+work everywhere.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import dotprod as _dotprod
 from repro.kernels import spmv as _spmv
 from repro.kernels import stencil7 as _stencil7
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_FORCE_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
     return jax.default_backend() != "tpu"
 
 
@@ -55,13 +49,14 @@ def spmv_hex_dot(P, c_diag: float, c_off: float, block=(8, 128)):
     return av, jnp.sum(partials, dtype=jnp.float32)
 
 
-def dual_dot(a, b, c, d, block=(256, 128)):
-    """Brick-local fused dual dot: returns jnp.stack([a·b, c·d])."""
-    def to2d(x):
-        n = x.size
-        cols = 128 if n % 128 == 0 else 1
-        return x.reshape(n // cols, cols)
+def dual_dot(a, b, c, d):
+    """Brick-local pair of dot products: ``jnp.stack([a·b, c·d])``.
 
-    out = _dotprod.dual_dot_2d(to2d(a), to2d(b), to2d(c), to2d(d),
-                               block=block, interpret=_interpret())
-    return jnp.sum(out, axis=0, dtype=jnp.float32)
+    Plain jnp on purpose: XLA fuses the two reductions into one
+    multi-output fusion that reads each distinct operand once — the
+    solvers' pairs share operands (``(r, z, r, r)``, ``(r, r, w, r)``), so
+    that single sweep moves less HBM than a kernel taking four operands.
+    Accumulates in at least fp32 (fp64 operands stay fp64).
+    """
+    acc = jnp.promote_types(a.dtype, jnp.float32)
+    return jnp.stack([jnp.sum(a * b, dtype=acc), jnp.sum(c * d, dtype=acc)])

@@ -44,9 +44,3 @@ def stencil_planes_ref(T, xlo, xhi, ylo, yhi, coords, c_diag, c_off,
     interior = ((gx > 0) & (gx < nx - 1) & (gy > 0) & (gy < ny - 1)
                 & (zi > 0) & (zi < nz - 1))
     return jnp.where(jnp.asarray(interior), out, T)
-
-
-def dual_dot_ref(a, b, c, d):
-    """Oracle for kernels.dotprod.dual_dot_2d — (a·b, c·d) as a (2,) vec."""
-    return jnp.stack([jnp.sum(a * b, dtype=jnp.float32),
-                      jnp.sum(c * d, dtype=jnp.float32)])

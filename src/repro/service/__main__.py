@@ -71,6 +71,9 @@ def main(argv=None) -> int:
     if not args.smoke:
         _build_parser().print_help()
         return 0
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     import tempfile
 

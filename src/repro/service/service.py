@@ -71,8 +71,9 @@ from repro.service.workloads import (
 
 log = logging.getLogger("repro.service")
 
-#: exceptions that retrying cannot fix (bad request, unknown workload)
-_PERMANENT = (ValueError, KeyError, TypeError)
+#: exceptions that retrying cannot fix (bad request, unknown workload, an
+#: operation the compiler cannot lower for this device)
+_PERMANENT = (ValueError, KeyError, TypeError, NotImplementedError)
 
 
 class SimulationService:

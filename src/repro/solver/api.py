@@ -619,15 +619,8 @@ def make_solver(
             return jnp.sum(a * b, dtype=jnp.promote_types(a.dtype, jnp.float32))
 
     def dot2(a, b, c, d):
-        from repro.kernels import ops as kops
-
-        # the fused dual-dot kernel is a Mosaic win (one operand sweep); in
-        # interpret mode (this CPU container) a pallas launch per reduction
-        # only adds overhead — the BENCH_resident run caught PCG paying it
-        # per iteration — so the correctness path keeps the jnp reductions
-        if batch == 1 and backend == "pallas" and not kops._interpret():
-            part = kops.dual_dot(a, b, c, d)  # one fused operand sweep
-            return part[0], part[1]
+        # XLA fuses the pair into one multi-output reduction (one sweep of
+        # the shared operands; see repro.kernels.ops.dual_dot)
         return dot(a, b), dot(c, d)
 
     run = _make_runner(
@@ -777,16 +770,7 @@ def make_sharded_solver(
     def _psum_dot2(a, b, c, d):
         from repro.kernels import ops as kops
 
-        # see make_solver's dot2: fused kernel on Mosaic only
-        if backend == "pallas" and not kops._interpret():
-            part = kops.dual_dot(a, b, c, d)  # fused local pass
-        else:
-            part = jnp.stack(
-                [
-                    jnp.sum(a * b, dtype=jnp.float32),
-                    jnp.sum(c * d, dtype=jnp.float32),
-                ]
-            )
+        part = kops.dual_dot(a, b, c, d)  # one fused local pass
         part = jax.lax.psum(part, (ax_x, ax_y))  # ONE fused all-reduce
         return part[0], part[1]
 
@@ -948,16 +932,21 @@ def _recover_solve(program, name, first, x0, policy, kwargs, member_env):
             return (x, iters, res, outs), trace
 
     # rung 3: one fp64 safe-mode re-solve of the original system (the
-    # x64 context covers both build and run — tracing happens at call time)
+    # x64 context covers both build and run — tracing happens at call time).
+    # Mosaic compiles no float64 kernel, so on a TPU the rung runs the
+    # roll interpreter under XLA (backend="jit"), which takes float64.
     if failed(outs) and policy.safe_mode_fp64 and dtype != np.float64:
-        from jax.experimental import enable_x64
+        from repro.kernels.ops import _interpret
 
         why = health.outcome_name(health.worst(outs))
         p64 = _cast_program(program, np.float64)
         env64 = {k: np.asarray(v, np.float64) for k, v in member_env.items()}
-        with enable_x64():
+        kw64 = kwargs
+        if kwargs["backend"] == "pallas" and not _interpret():
+            kw64 = dict(kwargs, backend="jit")
+        with jax.enable_x64(True):
             ok = _attempt(
-                kwargs,
+                kw64,
                 p64,
                 np.asarray(x0, np.float64),
                 f"fp64 safe mode after {why}",

@@ -501,7 +501,8 @@ def build():
                 + T[1:-1, -1, 0] + T[1:-1, 0, 1] + T[1:-1, 0, -1])
     return prog
 
-mesh = jax.make_mesh((2, 2), ("x", "y"))
+from repro.core.jaxcompat import make_mesh
+mesh = make_mesh((2, 2), ("x", "y"))
 opts = wfa.RunOptions(backend="pallas", differentiable=True)
 r1 = differentiable_runner(plan(build(), options=opts))
 r2 = differentiable_runner(plan(build(), options=opts.replace(mesh=mesh)))

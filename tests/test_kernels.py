@@ -75,14 +75,21 @@ def test_spmv_dot_odd_shapes_blocks(rng, shape, block):
     np.testing.assert_allclose(float(d), float(rd), rtol=1e-4)
 
 
-@pytest.mark.parametrize("block", [(256, 128), (64, 32), (16, 8)])
-def test_dual_dot_blocks(rng, block):
-    a, b, c, d = [jnp.asarray(rng.normal(size=(12, 64, 4)).astype(np.float32))
+def _dots64(a, b, c, d):
+    f = lambda x: np.asarray(x, np.float64).ravel()  # noqa: E731
+    return np.array([f(a) @ f(b), f(c) @ f(d)])
+
+
+@pytest.mark.parametrize("pattern", ["distinct", "cg", "pipecg"])
+def test_dual_dot_blocks(rng, pattern):
+    """The operand-sharing pairs the Krylov solvers pass, vs fp64 numpy."""
+    r, w, z, v = [jnp.asarray(rng.normal(size=(12, 64, 4)).astype(np.float32))
                   for _ in range(4)]
-    out = ops.dual_dot(a, b, c, d, block=block)
-    expect = ref.dual_dot_ref(a, b, c, d)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
-                               rtol=2e-4)
+    args = {"distinct": (r, w, z, v), "cg": (r, z, r, r),
+            "pipecg": (r, r, w, r)}[pattern]
+    out = ops.dual_dot(*args)
+    assert out.shape == (2,) and out.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out), _dots64(*args), rtol=2e-4)
 
 
 @pytest.mark.parametrize("shape", [(16, 64, 8), (4, 4, 4), (32, 128, 2)])
@@ -90,8 +97,7 @@ def test_dual_dot_sweep(rng, shape):
     a, b, c, d = [jnp.asarray(rng.normal(size=shape).astype(np.float32))
                   for _ in range(4)]
     out = ops.dual_dot(a, b, c, d)
-    expect = ref.dual_dot_ref(a, b, c, d)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+    np.testing.assert_allclose(np.asarray(out), _dots64(a, b, c, d),
                                rtol=2e-4)
 
 

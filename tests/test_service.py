@@ -261,6 +261,25 @@ def test_fault_without_checkpoints_restarts_from_scratch(warm_service):
     assert t.stats.retries == 1 and t.stats.restores == 0
 
 
+def test_compile_refusal_fails_fast_without_retries(warm_service):
+    """An operation the compiler cannot lower (``NotImplementedError``) is
+    permanent: the ticket fails on the first attempt, with no retry."""
+    req = StepRequest(SIGS[0], steps=4, ckpt_every=2)
+
+    def refuse(step, tag=""):
+        if tag == req.request_id:
+            raise NotImplementedError("no lowering for this op")
+
+    prev = hooks.set_step_hook(refuse)
+    try:
+        t = warm_service.submit(req)
+        with pytest.raises(NotImplementedError, match="no lowering"):
+            t.result(timeout=300)
+    finally:
+        hooks.set_step_hook(prev)
+    assert t.stats.retries == 0 and t.stats.restores == 0
+
+
 def test_retry_budget_exhaustion_fails_the_ticket(warm_service):
     req = StepRequest(SIGS[0], steps=4)
 

@@ -1,0 +1,162 @@
+"""Compile-only tests: the main path's kernels through Mosaic for a v5e.
+
+Nothing here runs on a chip.  Each test compiles a kernel (or the jitted
+step around it) for a described, unattached ``v5e:2x2`` topology at the
+paper's industrial size, so a kernel Mosaic refuses — a misaligned block,
+an op with no TPU lowering, a window past the VMEM limit — fails here
+instead of on the chip.  Every compile passes ``interpret=False`` itself.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+from __future__ import annotations
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
+
+from repro.compiler import auto_tile, lower_group
+from repro.compiler.codegen import compile_group, compile_group_sharded
+from repro.configs.heat3d import HeatConfig
+from repro.core.jaxcompat import shard_map
+from repro.core.program import _group_ops
+
+HEAT = HeatConfig()
+SHAPE = (HEAT.nx, HEAT.ny, HEAT.nz)    # 512 x 512 x 128, 3.3e7 cells
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip can be written to the persistent
+    # cache but never read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _heat_ops(shape):
+    """The Fig. 3 heat body recorded on ``shape`` (the service's heat3d)."""
+    from repro.service.workloads import _record_heat3d
+
+    program, _ = _record_heat3d(shape, np.float32, 40000)
+    (loop, ops), = [g for g in _group_ops(program) if g[0] is not None]
+    return loop, ops
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _auto_k():
+    loop, ops = _heat_ops(SHAPE)
+    group = lower_group(ops)
+    fields = {"T": (HEAT.nz, np.float32)}
+    return auto_tile(group, SHAPE[:2], loop.n, fields=fields)
+
+
+@pytest.mark.parametrize("k", ["1", "auto"])
+def test_heat_fused_kernel_compiles(one_chip, k):
+    k = 1 if k == "1" else _auto_k()
+    assert k >= 1
+    _, ops = _heat_ops(SHAPE)
+    step = compile_group(ops, {"T": SHAPE}, {"T": np.float32},
+                         interpret=False, time_tile=k)
+    x = jax.ShapeDtypeStruct(SHAPE, jnp.float32, sharding=one_chip)
+    _compile(step, {"T": x})
+
+
+def test_auto_tile_fits_vmem_at_industrial_size():
+    """At 512x512x128 the static rule stops below k=8: the k=8 window
+    leaves too small an X block (or none) inside the kernel's VMEM."""
+    from repro.compiler.ir import fused_x_block
+
+    k = _auto_k()
+    loop, ops = _heat_ops(SHAPE)
+    group = lower_group(ops)
+    fields = {"T": (HEAT.nz, np.float32)}
+    bxb = fused_x_block(group, k, SHAPE[:2], fields)
+    assert 1 < k < 8 and bxb >= 4 * k * group.halo
+
+
+def test_heat_resident_kernel_compiles(one_chip):
+    _, ops = _heat_ops(SHAPE)
+    k = 2
+    margin = k  # the halo-resident layout's run-wide margin, k·h
+    step = compile_group(ops, {"T": SHAPE}, {"T": np.float32},
+                         interpret=False, time_tile=k, resident=margin)
+    padded = (SHAPE[0] + 2 * margin, SHAPE[1] + 2 * margin, SHAPE[2])
+    x = jax.ShapeDtypeStruct(padded, jnp.float32, sharding=one_chip)
+    _compile(step, {"T": x})
+
+
+def test_heat_sharded_step_compiles(topo):
+    """One fused step inside shard_map on a 2x2 mesh: 256x256x128 bricks,
+    halo exchange by ppermute."""
+    _, ops = _heat_ops(SHAPE)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    step = compile_group_sharded(ops, {"T": SHAPE}, {"T": np.float32},
+                                 mesh_xy=(2, 2), axis_names=("data", "model"),
+                                 interpret=False)
+    spec = PartitionSpec("data", "model", None)
+    mapped = shard_map(step, mesh=mesh, in_specs=({"T": spec},),
+                       out_specs={"T": spec})
+    x = jax.ShapeDtypeStruct(SHAPE, jnp.float32,
+                             sharding=NamedSharding(mesh, spec))
+    text = _compile(mapped, {"T": x}).as_text()
+    assert "collective-permute" in text
+
+
+def test_dual_dot_fuses_one_sweep(one_chip):
+    """The solvers' reduction pair is one multi-output XLA fusion that
+    reads the shared operand once (see repro.kernels.ops.dual_dot)."""
+    from repro.kernels import ops as kops
+
+    x = jax.ShapeDtypeStruct(SHAPE, jnp.float32, sharding=one_chip)
+    text = jax.jit(lambda r, z: kops.dual_dot(r, z, r, r)).lower(
+        x, x).compile().as_text()
+    # fusions that read the full-size operands: one, yielding both sums
+    sweeps = re.findall(r"= (\([^=]*\)|\S+) fusion\((%r[^,]*, %z[^)]*)\)",
+                        text)
+    assert len(sweeps) == 1, sweeps
+    assert sweeps[0][0].count("f32[]") == 2, sweeps
+
+
+def test_mg_smoother_compiles(one_chip):
+    """The damped-Jacobi smoother at the finest level of a 129^3 Poisson."""
+    from repro.compiler import mg_fine_operator
+    from repro.solver.api import _split
+    from repro.solver.multigrid import JACOBI_OMEGA, _record_smoother
+    from repro.solver.presets import poisson_program
+
+    n = 129
+    program = poisson_program((n, n, n))
+    (_, op_ops), _ = _split(program, "T")
+    fine = mg_fine_operator(lower_group(op_ops), "T", (n, n, n))
+    ops, shapes, dtypes = _record_smoother(fine, JACOBI_OMEGA, np.float32)
+    step = compile_group(ops, shapes, dtypes, interpret=False)
+    x = jax.ShapeDtypeStruct((n, n, n), jnp.float32, sharding=one_chip)
+    _compile(step, {"x": x, "b": x})

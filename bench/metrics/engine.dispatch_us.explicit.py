@@ -1,0 +1,8 @@
+"""Host us per chunk of the window inside the program's
+``wfa.engine.dispatch`` spans (one per call of the engine's runner): the
+engine's own dispatch, on the host clock alone."""
+from bench.harness import scopes
+
+
+def read(ctx):
+    return scopes.dispatch_us(ctx, "wfa.engine.dispatch", "dispatch")

@@ -313,7 +313,8 @@ def compile_group(ops, shapes: Dict[str, tuple], dtypes: Dict[str, object],
 
         def step(env):
             env = dict(env)
-            ins = [wrap_refresh(env[n], resident, ph) for n in in_names]
+            with jax.named_scope("wfa.engine.margin_refresh"):
+                ins = [wrap_refresh(env[n], resident, ph) for n in in_names]
             # pin the fusion boundary at the kernel inputs: XLA otherwise
             # fuses the margin producer (refresh here, pad on the legacy
             # path) into the kernel's first ops, and the differing contexts
@@ -333,13 +334,14 @@ def compile_group(ops, shapes: Dict[str, tuple], dtypes: Dict[str, object],
     def step(env):
         env = dict(env)
         padded = []
-        for n in in_names:
-            v = env[n]
-            if ph:
-                widths = ((0, 0),) * (v.ndim - 3) + (
-                    (ph, ph), (ph, ph), (0, 0))
-                v = jnp.pad(v, widths, mode="wrap")
-            padded.append(v)
+        with jax.named_scope("wfa.engine.wrap_pad"):
+            for n in in_names:
+                v = env[n]
+                if ph:
+                    widths = ((0, 0),) * (v.ndim - 3) + (
+                        (ph, ph), (ph, ph), (0, 0))
+                    v = jnp.pad(v, widths, mode="wrap")
+                padded.append(v)
         padded = list(jax.lax.optimization_barrier(tuple(padded)))
         outs = call(*padded)
         for name, out in zip(written, outs):

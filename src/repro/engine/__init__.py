@@ -13,7 +13,8 @@ behind ``wfa.solve`` — dispatches through this package:
   the one backend if/else in the tree — for the solver's matrix-free
   operator steps;
 * :data:`stats` exposes the communication accounting (steps, launches,
-  halo exchanges / wrap pads, tiles fused, steps/sec).
+  halo exchanges / wrap pads, tiles fused); :func:`span`, :func:`spans`
+  and :func:`device_scopes` name the program's host and device work.
 
 Temporal blocking: a fused segment with ``time_tile=k`` advances k steps
 per kernel launch off one halo exchange (or wrap pad) of depth ``k·h`` —
@@ -44,7 +45,15 @@ from repro.engine.plan import (
     plan,
     plan_mg_levels,
 )
-from repro.engine.stats import EngineStats, reset_stats, service_stats, stats
+from repro.engine.stats import (
+    EngineStats,
+    device_scopes,
+    reset_stats,
+    service_stats,
+    span,
+    spans,
+    stats,
+)
 
 __all__ = [
     "BACKENDS",
@@ -58,6 +67,7 @@ __all__ = [
     "Segment",
     "UNSET",
     "compile_body",
+    "device_scopes",
     "execute",
     "health",
     "plan",
@@ -70,5 +80,7 @@ __all__ = [
     "service_stats",
     "sharded_runner",
     "single_runner",
+    "span",
+    "spans",
     "stats",
 ]

@@ -25,7 +25,6 @@ the plan (see :mod:`repro.engine.stats`).
 
 from __future__ import annotations
 
-import time
 from typing import Dict
 
 import jax
@@ -35,7 +34,7 @@ import numpy as np
 from repro.core.program import _apply_op
 from repro.engine.hooks import fire_step_hook
 from repro.engine.plan import ExecutionPlan, Segment
-from repro.engine.stats import stats
+from repro.engine.stats import record_program, span, stats
 
 
 def _apply_segment(seg: Segment, env):
@@ -83,10 +82,9 @@ def _trace_plan(plan: ExecutionPlan, env):
             env = _apply_segment(seg, env)
         return env
     for ev in _layout_schedule(plan):
-        if ev == "enter":
-            env = layout.enter(env)
-        elif ev == "exit":
-            env = layout.exit(env)
+        if isinstance(ev, str):
+            with jax.named_scope("wfa.engine.layout"):
+                env = layout.enter(env) if ev == "enter" else layout.exit(env)
         else:
             env = _apply_segment(ev, env)
     return env
@@ -161,8 +159,11 @@ def _run_numpy(plan: ExecutionPlan, env: Dict[str, np.ndarray], check: int = 0):
             _run_numpy_one(plan, {k: v[b] for k, v in env.items()}, check)
             for b in range(plan.batch)
         ]
-        return {k: np.stack([o[k] for o in outs]) for k in env}
-    return _run_numpy_one(plan, env, check)
+        out = {k: np.stack([o[k] for o in outs]) for k in env}
+    else:
+        out = _run_numpy_one(plan, env, check)
+    _account(plan)
+    return out
 
 
 def _run_numpy_one(plan: ExecutionPlan, env: Dict[str, np.ndarray], check=0):
@@ -193,11 +194,24 @@ def _run_numpy_one(plan: ExecutionPlan, env: Dict[str, np.ndarray], check=0):
     return env
 
 
+def _arg_spec(plan: ExecutionPlan):
+    """The env a runner of ``plan`` takes, as shapes: every program field,
+    with the ensemble axis in front on a batched plan."""
+    lead = (plan.batch,) if plan.batch > 1 else ()
+    return {
+        n: jax.ShapeDtypeStruct(lead + tuple(f.shape), f.dtype)
+        for n, f in plan.program.fields.items()
+    }
+
+
 def single_runner(plan: ExecutionPlan):
     """The jitted single-device runner for ``plan`` (entry env donated).
 
     Exposed for the residency tests: ``runner.lower(env)`` shows the
     donation markers and ``runner(env)`` consumes its argument buffers.
+    Each call is one ``wfa.engine.dispatch`` span and adds the plan's
+    counts to :data:`repro.engine.stats`; the jitted program is recorded
+    for :func:`repro.engine.stats.device_scopes`.
 
     A :class:`~repro.engine.plan.ExecutionPlan` built with
     ``RunOptions(differentiable=True)`` is **not** donated: under AD the
@@ -210,7 +224,17 @@ def single_runner(plan: ExecutionPlan):
         return _trace_plan(plan, env)
 
     donate = () if plan.differentiable else (0,)
-    return jax.jit(run, donate_argnums=donate)
+    jitted = jax.jit(run, donate_argnums=donate)
+    record_program(jitted, (_arg_spec(plan),))
+
+    def runner(env):
+        with span("wfa.engine.dispatch"):
+            out = jitted(env)
+            _account(plan)
+        return out
+
+    runner.lower = jitted.lower
+    return runner
 
 
 def _run_single(plan: ExecutionPlan, env):
@@ -251,6 +275,7 @@ def _run_sharded(plan: ExecutionPlan, env):
     stepped, sharding = sharded_runner(plan, names=list(env))
     genv = {k: jax.device_put(fresh_buffer(v), sharding) for k, v in env.items()}
     out = stepped(genv)
+    _account(plan)
     return {k: np.asarray(jax.device_get(v)) for k, v in out.items()}
 
 
@@ -467,12 +492,14 @@ def _run_guarded(plan: ExecutionPlan, env, every: int):
             chunked(seg.step, n, 1)
     if state["padded"]:
         env = exit_(env)
+    _account(plan)
     return {k: np.asarray(jax.device_get(v)) for k, v in env.items()}
 
 
 def execute(plan: ExecutionPlan, env: Dict[str, np.ndarray], options=None):
     """Run the plan from ``env`` (name -> (X, Y, Z) array); returns the final
-    env as host NumPy arrays.  Updates :data:`repro.engine.stats`.
+    env as host NumPy arrays.  Updates :data:`repro.engine.stats`; the
+    call is one ``wfa.engine.execute`` span.
 
     Fires the engine's step hook (:mod:`repro.engine.hooks`) before any
     state advances, so an installed fault injector interrupts the run where
@@ -486,18 +513,16 @@ def execute(plan: ExecutionPlan, env: Dict[str, np.ndarray], options=None):
     """
     check = int(getattr(options, "check_finite", 0) or 0)
     fire_step_hook(stats.steps_run, tag="execute")
-    t0 = time.perf_counter()
-    if plan.backend == "numpy":
-        out = _run_numpy(plan, env, check)
-    elif check > 0:
-        out = _run_guarded(plan, env, check)
-    elif plan.mesh is None:
-        out = _run_single(plan, env)
-    else:
-        out = _run_sharded(plan, env)
-    stats.elapsed_s += time.perf_counter() - t0
-    _account(plan)
-    return {k: np.asarray(v) for k, v in out.items()}
+    with span("wfa.engine.execute"):
+        if plan.backend == "numpy":
+            out = _run_numpy(plan, env, check)
+        elif check > 0:
+            out = _run_guarded(plan, env, check)
+        elif plan.mesh is None:
+            out = _run_single(plan, env)
+        else:
+            out = _run_sharded(plan, env)
+        return {k: np.asarray(v) for k, v in out.items()}
 
 
 def run_program(

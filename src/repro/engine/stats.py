@@ -1,10 +1,24 @@
-"""Execution counters for the unified engine.
+"""Execution counters, host spans and device scopes for the unified engine.
 
 One global :data:`stats` instance (mirroring ``repro.compiler.stats``) that
-:func:`repro.engine.plan` and :func:`repro.engine.execute` update in place;
+:func:`repro.engine.plan`, the runner of :func:`repro.engine.single_runner`
+(once per dispatch) and :func:`repro.engine.execute` update in place;
 tests and benchmarks ``reset_stats()`` around a run and assert on the
 communication accounting — the headline being :attr:`EngineStats.
 exchanges_per_step`, which temporal blocking must drop k×.
+
+Spans and scopes name the program's own work, ``wfa.<layer>.<what>``:
+
+* :func:`span` brackets host code (dispatch, the solver's entry copy,
+  ``execute``): a ``jax.profiler.TraceAnnotation`` for a profiler trace,
+  and a record in a bounded in-memory ring on the host clock
+  (``time.perf_counter_ns``), read with :func:`spans`;
+* ``jax.named_scope`` names device work inside the jitted programs
+  (``wfa.engine.wrap_pad``, ``.margin_refresh``, ``.layout``,
+  ``wfa.kernel.stencil``, ``wfa.krylov.dot``, ``.update``); the runners
+  :func:`record_program` what they compile, and :func:`device_scopes`
+  maps each compiled instruction to its innermost scope, so a device
+  trace's operations can be split by the code that issued them.
 
 Exchange counting is *static*: execution is traced (``lax.fori_loop`` /
 ``shard_map``), so the executor derives the counts from the plan — one pad /
@@ -16,8 +30,15 @@ step).
 
 from __future__ import annotations
 
+import collections
 import dataclasses
-from typing import Tuple
+import re
+import threading
+import time
+import weakref
+from typing import Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -51,7 +72,6 @@ class EngineStats:
     #: in-place halo-resident layout (see :func:`repro.engine.plan`)
     resident_dropped: int = 0
     max_time_tile: int = 1  # largest k any segment ran with
-    elapsed_s: float = 0.0  # wall time inside execute()
     tile_reasons: Tuple[str, ...] = ()  # why a tile factor was clamped/refused
 
     # -- exchange/compute overlap (interior/boundary split segments) ---------
@@ -105,11 +125,6 @@ class EngineStats:
         """Halo exchanges (or wrap pads) per logical time step."""
         return self.exchanges / self.steps_run if self.steps_run else 0.0
 
-    @property
-    def steps_per_sec(self) -> float:
-        """Logical time steps per wall-clock second across executes."""
-        return self.steps_run / self.elapsed_s if self.elapsed_s else 0.0
-
     def note_tile_reason(self, reason: str) -> None:
         self.tile_reasons = self.tile_reasons + (reason,)
 
@@ -121,6 +136,122 @@ def reset_stats() -> None:
     # mutate in place so `from repro.engine import stats` stays live
     for f in dataclasses.fields(EngineStats):
         setattr(stats, f.name, f.default)
+    _ring.clear()
+
+
+# ---------------------------------------------------------------------------
+# host spans
+# ---------------------------------------------------------------------------
+
+#: spans kept in memory, newest last; older ones fall off the ring
+SPAN_RING = 1 << 16
+
+#: ``(name, start_ns, end_ns, parent)`` per closed span
+_ring: collections.deque = collections.deque(maxlen=SPAN_RING)
+_open = threading.local()
+
+
+class span:
+    """Context manager naming a stretch of host code ``name``.
+
+    It enters a ``jax.profiler.TraceAnnotation(name)``, so a profiler trace
+    shows the span on its own clock, and on exit appends ``(name,
+    start_ns, end_ns, parent)`` to the in-memory ring that :func:`spans`
+    reads: times from ``time.perf_counter_ns()``, ``parent`` the name of
+    the span open on the same thread when this one began (``None`` at the
+    top).  Always on; no device work and no sync.
+
+    >>> from repro.engine.stats import reset_stats, span, spans
+    >>> reset_stats()
+    >>> with span("wfa.doc.outer"):
+    ...     with span("wfa.doc.inner"):
+    ...         pass
+    >>> [(name, parent) for name, _, _, parent in spans()]
+    [('wfa.doc.inner', 'wfa.doc.outer'), ('wfa.doc.outer', None)]
+    """
+
+    __slots__ = ("name", "_parent", "_note", "_t0")
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def __enter__(self):
+        stack = _open.__dict__.setdefault("stack", [])
+        self._parent = stack[-1] if stack else None
+        stack.append(self.name)
+        self._note = TraceAnnotation(self.name)
+        self._note.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter_ns()
+        self._note.__exit__(*exc)
+        _open.stack.pop()
+        _ring.append((self.name, self._t0, t1, self._parent))
+        return False
+
+
+def spans() -> List[Tuple[str, int, int, Optional[str]]]:
+    """The closed spans in the ring, oldest first."""
+    return list(_ring)
+
+
+# ---------------------------------------------------------------------------
+# device scopes
+# ---------------------------------------------------------------------------
+
+#: jitted function -> its argument shapes, for every program a runner built
+#: (weakly held: a runner's program is forgotten with the runner)
+_programs: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%(\S+)\s+=\s+(.*?)\s[a-z][a-z0-9\-]*\(")
+_SHAPE = re.compile(r"\b(pred|bf16|[fsuc][0-9]+)\[([0-9,]*)\]")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_SCOPE = re.compile(r"wfa\.[a-z0-9_]+\.[a-z0-9_]+")
+
+
+def record_program(fn, args) -> None:
+    """Remember jitted ``fn`` and the arguments (arrays or
+    ``jax.ShapeDtypeStruct``s) it runs on, for :func:`device_scopes`."""
+    _programs[fn] = args
+
+
+def instruction_key(text: str):
+    """``(name, result shapes)`` of one instruction line of compiled HLO
+    text, as a device trace prints it: ``("fusion.6",
+    ("f32[516,516,128]",))``; ``None`` for a line that is not one."""
+    m = _INSTRUCTION.match(text)
+    if m is None:
+        return None
+    return m.group(1), tuple(f"{dt}[{dims}]" for dt, dims in _SHAPE.findall(m.group(2)))
+
+
+def device_scopes() -> Dict[tuple, str]:
+    """Map each instruction of every recorded program, keyed by
+    :func:`instruction_key`, to the innermost ``wfa.*`` scope of its
+    ``op_name``; instructions outside every scope are left out.
+
+    Lowers and compiles each program again (a hit in JAX's compile caches
+    where the program ran) and reads its compiled text.  Instruction names
+    are unique only within one program, so a key that two recorded
+    programs give different scopes, or one a scope and the other none, is
+    left out too: an operation is never put under a scope it may not
+    belong to.  Programs that were not recorded are not checked."""
+    seen: Dict[tuple, Optional[str]] = {}
+    clash = set()
+    for fn, args in list(_programs.items()):
+        text = fn.lower(*args).compile().as_text()
+        for line in text.splitlines():
+            key = instruction_key(line)
+            if key is None:
+                continue
+            name = _OP_NAME.search(line)
+            found = _SCOPE.findall(name.group(1)) if name else []
+            scope = found[-1] if found else None
+            if seen.setdefault(key, scope) != scope:
+                clash.add(key)
+    return {k: s for k, s in seen.items() if s is not None and k not in clash}
 
 
 def service_stats() -> dict:

@@ -359,12 +359,14 @@ def build_fused_call(updates: Sequence, field_specs: Dict[str, Tuple[int, object
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
+        name="wfa_stencil",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",), vmem_limit_bytes=VMEM_LIMIT),
     )
 
     def fused(coords, *padded):
-        out = call(coords, *padded)
+        with jax.named_scope("wfa.kernel.stencil"):
+            out = call(coords, *padded)
         return tuple(out) if isinstance(out, (list, tuple)) else (out,)
 
     return fused, tuple(written)

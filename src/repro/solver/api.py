@@ -37,6 +37,7 @@ import numpy as np
 
 from repro.compiler import LoweringError, Tap, lower_group
 from repro.core.program import Program, _group_ops, release_program
+from repro.engine.stats import record_program, span
 from repro.solver import health, krylov
 
 log = logging.getLogger("repro.solver")
@@ -607,16 +608,13 @@ def make_solver(
     # fp32 accumulation matches the wafer reductions; the fp64 safe-mode
     # rung widens the operands, and its dots must widen with them or the
     # re-solve inherits the very overflow it is escaping
-    if batch > 1:
+    # per-member reductions over the trailing (X, Y, Z) axes when batched
+    axes = (1, 2, 3) if batch > 1 else None
 
-        def dot(a, b):
-            # per-member reduction over the trailing (X, Y, Z) axes
-            return jnp.sum(a * b, axis=(1, 2, 3), dtype=jnp.promote_types(a.dtype, jnp.float32))
-
-    else:
-
-        def dot(a, b):
-            return jnp.sum(a * b, dtype=jnp.promote_types(a.dtype, jnp.float32))
+    def dot(a, b):
+        with jax.named_scope("wfa.krylov.dot"):
+            acc = jnp.promote_types(a.dtype, jnp.float32)
+            return jnp.sum(a * b, axis=axes, dtype=acc)
 
     def dot2(a, b, c, d):
         # XLA fuses the pair into one multi-output reduction (one sweep of
@@ -645,11 +643,17 @@ def make_solver(
     # rest of the iteration is already allocation-free — XLA aliases the
     # carry); step_fn hands in a buffer the caller never owned.
     jitted = jax.jit(run, donate_argnums=0)
+    lead = (batch,) if batch > 1 else ()
+    spec = jax.ShapeDtypeStruct(lead + tuple(shape), field.dtype)
+    record_program(jitted, (spec, *coefs))
 
     def step_fn(x0):
         from repro.engine.executor import fresh_buffer
 
-        return jitted(fresh_buffer(x0), *coefs)
+        with span("wfa.solver.dispatch"):
+            with span("wfa.solver.copy_x0"):
+                x0 = fresh_buffer(x0)
+            return jitted(x0, *coefs)
 
     return step_fn
 

@@ -50,6 +50,10 @@ from repro.solver import health
 
 _TINY = 1e-30
 
+#: device scope of CG's vector updates (the caller's ``dot`` names its
+#: reductions ``wfa.krylov.dot``)
+UPDATE = "wfa.krylov.update"
+
 
 def _nonzero(d):
     """Clamp a denominator away from zero, keeping its sign (fp32 guard)."""
@@ -83,8 +87,10 @@ def cg(
     donate_argnums=...)``) so the whole iteration is allocation-free.
     """
     guard = guard or health.DEFAULT_GUARD
+    Ax0 = A(x0)
+    with jax.named_scope(UPDATE):
+        r = b - Ax0
     if M is None:
-        r = b - A(x0)
         p = r
         rr = dot(r, r)
         g0 = health.guard_init(rr)
@@ -97,12 +103,14 @@ def cg(
             x, r, p, rr, i, g = s
             Ap = A(p)
             pAp = dot(p, Ap)  # reduction 1
-            alpha = rr / pAp
-            x = x + alpha * p
-            r = r - alpha * Ap
+            with jax.named_scope(UPDATE):
+                alpha = rr / pAp
+                x = x + alpha * p
+                r = r - alpha * Ap
             rr_new = dot(r, r)  # reduction 2 (overlaps x-update)
-            beta = rr_new / rr
-            p = r + beta * p
+            with jax.named_scope(UPDATE):
+                beta = rr_new / rr
+                p = r + beta * p
             g = health.guard_update(g, rr_new, config=guard)
             return (x, r, p, rr_new, i + 1, g)
 
@@ -111,7 +119,6 @@ def cg(
 
     if dot2 is None:
         dot2 = lambda a, b_, c, d: (dot(a, b_), dot(c, d))  # noqa: E731
-    r = b - A(x0)
     z = M(r)
     p = z
     rz, rr = dot2(r, z, r, r)
@@ -124,13 +131,16 @@ def cg(
     def pbody(s):
         x, r, p, rz, rr, i, g = s
         Ap = A(p)
-        alpha = rz / _nonzero(dot(p, Ap))
-        x = x + alpha * p
-        r = r - alpha * Ap
+        pAp = dot(p, Ap)
+        with jax.named_scope(UPDATE):
+            alpha = rz / _nonzero(pAp)
+            x = x + alpha * p
+            r = r - alpha * Ap
         z = M(r)
         rz_new, rr_new = dot2(r, z, r, r)  # ONE fused reduction
-        beta = rz_new / _nonzero(rz)
-        p = z + beta * p
+        with jax.named_scope(UPDATE):
+            beta = rz_new / _nonzero(rz)
+            p = z + beta * p
         g = health.guard_update(g, rr_new, config=guard)
         return (x, r, p, rz_new, rr_new, i + 1, g)
 

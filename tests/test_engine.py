@@ -19,7 +19,7 @@ from repro.compiler import reset_stats as compiler_reset
 from repro.compiler import stats as compiler_stats
 from repro.configs.heat3d import HeatConfig, make_field
 from repro.core import WSE_Array, WSE_For_Loop, WSE_Interface
-from repro.engine import BACKENDS, plan, reset_stats, stats
+from repro.engine import BACKENDS, plan, reset_stats, spans, stats
 
 
 def build_heat(T0, steps, c=0.1):
@@ -108,7 +108,8 @@ def test_heat3d_k4_tiled_matches_untiled_one_pad_per_4_steps():
     # one wrap pad (the single-device exchange analogue) per 4 steps
     assert stats.exchanges_per_step == pytest.approx(0.25)
     assert stats.tiles_fused == 2 and stats.max_time_tile == 4
-    assert stats.steps_run == steps and stats.steps_per_sec > 0
+    (run,) = [s for s in spans() if s[0] == "wfa.engine.execute"]
+    assert stats.steps_run == steps and run[2] > run[1]
     # ftol match: identical arithmetic per sub-step; XLA FMA fusion may
     # round differently at the last ulp (on the ~500 K field that is ~6e-5)
     np.testing.assert_allclose(tiled, base, atol=1e-3)

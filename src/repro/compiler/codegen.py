@@ -170,7 +170,8 @@ def _build_overlap_step(group, specs, bx, by, nx, ny, interpret,
     1. **exchange in flight** — the depth-``k·h`` margin slabs are extracted
        (``slabs_fn``) into their own buffers, the *double-buffered margins*:
        the transfer never aliases the resident buffers the interior launch
-       is writing in place, so ``input_output_aliases`` stays valid.
+       is writing in place (a region launch aliases its inputs; interpret
+       mode only), so ``input_output_aliases`` stays valid.
     2. **interior launch** — the region at distance ``≥ k·h`` from every
        brick edge steps ``k`` sub-steps off a window contained in the brick:
        no margin reads, so nothing orders it after the exchange and the
@@ -252,11 +253,15 @@ def compile_group(ops, shapes: Dict[str, tuple], dtypes: Dict[str, object],
     ``resident=K`` switches to the halo-resident protocol (the engine's
     :class:`~repro.engine.layout.HaloLayout`): ``env`` holds ``(nx + 2K,
     ny + 2K, nz)`` buffers, the step refreshes only the depth-``k·h`` wrap
-    margin in place (:func:`repro.engine.layout.wrap_refresh` — four edge
-    slabs, no full-array repack) and the kernel writes back into the same
-    buffers via ``input_output_aliases``.  Bitwise identical to the
-    repacking step at every precision: the kernel sees the same window
-    values ``jnp.pad(mode="wrap")`` would have built.
+    rows above and below the brick in place
+    (:func:`repro.engine.layout.wrap_refresh_rows` — two edge slabs, no
+    full-array repack; the kernel builds the Y halo from the rows it
+    loads), and the kernel writes each written field into a fresh buffer
+    of the resident extent (double-buffered; see
+    :func:`repro.engine.executor.run_launches` for how the step loop keeps
+    that copy-free).  Bitwise identical to the repacking step at every
+    precision: the kernel sees the same window values
+    ``jnp.pad(mode="wrap")`` would have built.
 
     ``batch=B`` compiles an *ensemble* step: every env buffer carries a
     leading ``(B, ...)`` axis, the margin refresh / wrap pad and the
@@ -309,12 +314,13 @@ def compile_group(ops, shapes: Dict[str, tuple], dtypes: Dict[str, object],
     stats.groups_fused += 1
 
     if resident:
-        from repro.engine.layout import wrap_refresh
+        from repro.engine.layout import wrap_refresh_rows
 
         def step(env):
             env = dict(env)
             with jax.named_scope("wfa.engine.margin_refresh"):
-                ins = [wrap_refresh(env[n], resident, ph) for n in in_names]
+                ins = [wrap_refresh_rows(env[n], resident, ph)
+                       for n in in_names]
             # pin the fusion boundary at the kernel inputs: XLA otherwise
             # fuses the margin producer (refresh here, pad on the legacy
             # path) into the kernel's first ops, and the differing contexts
@@ -367,9 +373,9 @@ def compile_group_sharded(ops, shapes: Dict[str, tuple],
     ``resident=K`` switches to the halo-resident protocol: the brick env
     holds ``(bx + 2K, by + 2K, nz)`` buffers, the exchange moves only the
     four depth-``k·h`` margin slabs (:func:`repro.core.halo.halo_refresh` —
-    same ppermute traffic, no concatenated repack) and the kernel writes in
-    place via ``input_output_aliases``.  Bitwise identical to the repacking
-    step at every precision.
+    same ppermute traffic, no concatenated repack) and the kernel writes
+    double-buffered resident outputs, as on one device.  Bitwise identical
+    to the repacking step at every precision.
 
     ``overlap=True`` (resident mode only) splits each launch into an
     interior kernel — concurrent with the margin slabs' ``ppermute``

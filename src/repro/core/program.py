@@ -183,9 +183,10 @@ class WFAInterface:
         that warn once and forward into the bundle.
 
         Fused runs step on a *halo-resident* field layout (standing padded
-        buffers, in-place margin refresh + kernel outputs, donated entry
-        buffers — see :mod:`repro.engine.layout`); ``resident=False`` forces
-        the legacy repack-per-launch stepping, which is bitwise identical.
+        buffers, in-place margin refresh, double-buffered kernel outputs,
+        donated entry buffers — see :mod:`repro.engine.layout`);
+        ``resident=False`` forces the legacy repack-per-launch stepping,
+        which is bitwise identical.
 
         Example — three steps of pure decay on the interior (the Moat ring
         and the unwritten z planes keep their boundary values):

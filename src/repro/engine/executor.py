@@ -14,11 +14,14 @@ communication amortization lands: one halo exchange (or wrap pad) per tile.
 Halo residency (:mod:`repro.engine.layout`): when the plan carries a padded
 layout, the traced run *enters* it once (pad every field to the resident
 extent), steps the fused segments on those standing buffers — margin slabs
-refreshed in place, kernel outputs aliased — and *exits* once at the end;
-interpreter segments inside a mixed plan are bracketed by exit/enter so
-their roll semantics see plain arrays.  Both jitted executors **donate**
-their entry buffers (``donate_argnums``), so with an all-fused plan the
-whole step loop runs without allocating or repacking a single field copy.
+refreshed in place, each launch reading one buffer and writing another
+(double-buffered) — and *exits* once at the end; interpreter segments
+inside a mixed plan are bracketed by exit/enter so their roll semantics see
+plain arrays.  Launches run in pairs per loop iteration
+(:func:`run_launches`), so the two buffers of each written field trade
+places without a copy.  Both jitted executors **donate** their entry
+buffers (``donate_argnums``), so with an all-fused plan the whole step
+loop runs without repacking a single field.
 The executor also derives the engine's static communication accounting from
 the plan (see :mod:`repro.engine.stats`).
 """
@@ -37,17 +40,37 @@ from repro.engine.plan import ExecutionPlan, Segment
 from repro.engine.stats import record_program, span, stats
 
 
+def run_launches(step, n: int, env):
+    """Trace ``n`` launches of ``step``: pairs inside one ``fori_loop``, an
+    odd one after it.
+
+    A resident launch writes a fresh buffer, so its input and output are
+    live at once; with one launch per iteration XLA must copy the output
+    into the loop-carried buffer.  With two, the second output takes the
+    buffer of the first input, which is dead after the first launch, and
+    the loop runs copy-free.  Every step loop of the engine and the service
+    goes through here.
+    """
+    if n >= 2:
+        env = jax.lax.fori_loop(0, n // 2, lambda i, e: step(step(e)), env)
+    if n % 2:
+        env = step(env)
+    return env
+
+
+def step_segment(seg: Segment, n: int, env):
+    """Trace ``n`` logical steps of ``seg``: ``n // k`` tiled launches, then
+    ``n % k`` untiled ones (``k`` is 1 off the fused path)."""
+    k = seg.time_tile
+    env = run_launches(seg.step, n // k, env)
+    return run_launches(seg.step_rem, n % k, env)
+
+
 def _apply_segment(seg: Segment, env):
-    """Trace one segment: tiled launches + remainder, or the plain loop."""
+    """Trace one segment: its whole loop, or one application without one."""
     if seg.loop is None:
         return seg.step(env)
-    n, k = seg.loop.n, seg.time_tile
-    if k > 1:
-        env = jax.lax.fori_loop(0, n // k, lambda i, e: seg.step(e), env)
-        if n % k:
-            env = jax.lax.fori_loop(0, n % k, lambda i, e: seg.step_rem(e), env)
-        return env
-    return jax.lax.fori_loop(0, n, lambda i, e: seg.step(e), env)
+    return step_segment(seg, seg.loop.n, env)
 
 
 def _layout_schedule(plan: ExecutionPlan):
@@ -289,6 +312,9 @@ def _sentinel_fault(env, step_idx, last_good, good_step, exit_fn=None):
     from repro.engine import health as ehealth
 
     stats.numerical_faults += 1
+    if exit_fn is not None:
+        # resident state: look only at the interiors, margins are transient
+        env = exit_fn(env)
     bad = ehealth.poisoned_fields(env)
     if last_good is not None and exit_fn is not None:
         last_good = exit_fn(last_good)
@@ -323,11 +349,13 @@ def _guarded_wrap(plan: ExecutionPlan, fn, names):
     )
 
 
-def _guarded_loop_wrap(plan: ExecutionPlan, step_fn, per_chunk, names):
+def _guarded_loop_wrap(plan: ExecutionPlan, step_fn, per_chunk, names, pad=0):
     """One jitted guarded loop: up to ``nchunks`` iterations of
     ``per_chunk`` launches each, with the ``isfinite`` probe fused into the
     ``while_loop`` carry — a single dispatch per segment, stopping at the
-    first failed probe.
+    first failed probe.  ``pad`` is the resident margin of the env the loop
+    steps (0 for plain arrays); the probe reads only the interiors, since a
+    resident buffer's margins are transient.
 
     Returns a runner ``(env, nchunks) -> (env, chunks_run, ok)``.  The
     carry holds only the current state: keeping a last-good snapshot alive
@@ -344,16 +372,14 @@ def _guarded_loop_wrap(plan: ExecutionPlan, step_fn, per_chunk, names):
     mesh = plan.mesh
 
     def chunk(e):
-        return jax.lax.fori_loop(0, per_chunk, lambda i, ee: step_fn(ee), e)
+        return run_launches(step_fn, per_chunk, e)
 
-    if mesh is None:
-        probe = ehealth.probe_ok
-    else:
+    def probe(out):
+        ok = ehealth.probe_ok(out, pad)
+        if mesh is None:
+            return ok
         _, _, ax_x, ax_y = plan.mesh_ctx
-
-        def probe(out):
-            ok = ehealth.probe_ok(out)
-            return jax.lax.pmin(ok.astype(jnp.int32), (ax_x, ax_y)) > 0
+        return jax.lax.pmin(ok.astype(jnp.int32), (ax_x, ax_y)) > 0
 
     def run(env, nchunks):
         def body(c):
@@ -443,7 +469,8 @@ def _run_guarded(plan: ExecutionPlan, env, every: int):
         nonlocal env
         if chunks <= 0:
             return
-        runner = _guarded_loop_wrap(plan, step_fn, per_chunk, names)
+        pad = layout.pad if state["padded"] else 0
+        runner = _guarded_loop_wrap(plan, step_fn, per_chunk, names, pad)
         entry = env  # retained: the failure path replays the good prefix
         new_env, i, ok = runner(entry, chunks)
         i = int(jax.device_get(i))

@@ -28,17 +28,25 @@ from repro.solver.health import (  # noqa: F401  (re-exports)
 )
 
 
-def probe_ok(env) -> jnp.ndarray:
-    """Traceable scalar predicate: every buffer in ``env`` is all-finite."""
+def probe_ok(env, pad: int = 0) -> jnp.ndarray:
+    """Traceable scalar predicate: every buffer in ``env`` is all-finite.
+
+    ``pad`` is the margin of halo-resident buffers
+    (:class:`~repro.engine.layout.HaloLayout`): only their interiors are
+    read, since margins are transient and may hold anything between
+    launches.
+    """
     ok = jnp.bool_(True)
     for v in env.values():
+        if pad:
+            v = v[..., pad:-pad, pad:-pad, :]
         ok = ok & jnp.all(jnp.isfinite(v))
     return ok
 
 
 # compiled once per env tree/shape set: the eager per-op dispatch of the
 # reduction chain is what would blow the 2% probe budget, not the FLOPs
-probe_ok_compiled = jax.jit(probe_ok)
+probe_ok_compiled = jax.jit(probe_ok, static_argnums=1)
 
 
 def probe(env) -> bool:

@@ -15,18 +15,26 @@ Execution then touches memory three ways, none of which repacks a field:
 * **enter/exit** — one conversion at each *program boundary* (start and end
   of one ``execute``), never inside the step loop;
 * **margin refresh** — before a kernel launch reads a depth-``ph`` window,
-  only the four edge *slabs* are rewritten in place
-  (``dynamic_update_slice`` of wrap slabs on one device,
-  :func:`repro.core.halo.halo_refresh`'s ``ppermute`` slabs on a mesh);
-* **in-place outputs** — the fused kernels write back into the resident
-  buffers via ``pl.pallas_call(..., input_output_aliases=...)`` (see
-  :func:`repro.kernels.fused.build_fused_call`), and the executors donate
-  the entry buffers (``jax.jit(..., donate_argnums=...)``), so the step
-  loop allocates nothing per step.
+  only edge *slabs* are rewritten in place: on a mesh the four
+  :func:`repro.core.halo.halo_refresh` ``ppermute`` slabs; on one device
+  the two X slabs (:func:`wrap_refresh_rows`), since the kernel builds
+  each loaded row's Y halo from the row's own interior;
+* **double-buffered outputs** — a fused launch reads one resident buffer
+  and writes each written field into a second buffer of the same extent
+  (see :func:`repro.kernels.fused.build_fused_call`): the kernel's grid may
+  then run its blocks in sequence over HBM, as Mosaic does, without a
+  block's halo window reading rows an earlier block already stepped.  The
+  step loops run launches in pairs
+  (:func:`~repro.engine.executor.run_launches`), so each field's two
+  buffers trade places with no copy, and the executors donate the entry
+  buffers (``jax.jit(..., donate_argnums=...)``).
 
 Margin contents are *transient*: they are refreshed to depth ``ph`` right
-before each launch that reads them and are dead in between, so segments
-with different halo depths share one resident buffer safely.
+before each launch that reads them and are dead in between — a launch's
+output leaves its margin rows undefined — so segments with different halo
+depths share one resident buffer safely, and whatever inspects resident
+state between launches (a finiteness probe, a snapshot) reads the interior
+only.
 
 Every operation here is **rank-agnostic over leading axes**: batched
 ensemble plans (:class:`~repro.engine.options.RunOptions` with
@@ -149,19 +157,21 @@ def wrap_slabs(resident, margin: int, h: int) -> Dict[str, jnp.ndarray]:
 def land_slabs(resident, slabs: Dict[str, jnp.ndarray], margin: int, h: int):
     """Store extracted margin slabs into the resident buffer's margin frame.
 
-    The landing half of the refresh: four ``dynamic_update_slice`` writes at
-    the :func:`slab_rects` rectangles (disjoint, so order is irrelevant).
-    Leading (batch) axes pass through whole.
+    The landing half of the refresh: one ``dynamic_update_slice`` write per
+    given slab at its :func:`slab_rects` rectangle (disjoint, so order is
+    irrelevant).  Leading (batch) axes pass through whole.
     """
     if h == 0:
         return resident
     K = margin
     bx = resident.shape[-3] - 2 * K
     by = resident.shape[-2] - 2 * K
+    rects = slab_rects(bx, by, h)
     lead = (0,) * (resident.ndim - 3)
-    for name, (ox, oy, _, _) in slab_rects(bx, by, h).items():
+    for name, slab in slabs.items():
+        ox, oy, _, _ = rects[name]
         resident = jax.lax.dynamic_update_slice(
-            resident, slabs[name], lead + (K + ox, K + oy, 0)
+            resident, slab, lead + (K + ox, K + oy, 0)
         )
     return resident
 
@@ -182,6 +192,22 @@ def wrap_refresh(resident, margin: int, h: int):
     if h == 0:
         return resident
     return land_slabs(resident, wrap_slabs(resident, margin, h), margin, h)
+
+
+def wrap_refresh_rows(resident, margin: int, h: int):
+    """Refresh only the depth-``h`` X margin rows (``lo_x``/``hi_x``).
+
+    The single-device step's refresh: its kernel builds each loaded row's Y
+    halo from the row's own interior (see
+    :func:`repro.kernels.fused.build_fused_call`), so only the rows above
+    and below the brick have to be written — two runs of whole rows, where
+    the Y slabs would cost a narrow write into every row.
+    """
+    if h == 0:
+        return resident
+    slabs = wrap_slabs(resident, margin, h)
+    rows = {name: slabs[name] for name in ("lo_x", "hi_x")}
+    return land_slabs(resident, rows, margin, h)
 
 
 def strip_window(

@@ -61,7 +61,7 @@ class RunOptions:
     ``differentiable=True`` builds the run for reverse-mode AD: jitted
     runners stop donating their entry buffers (donated buffers cannot be
     saved as VJP residuals, and callers keep their arrays), plans skip the
-    halo-resident in-place layout, and ``wfa.solve`` routes through the
+    halo-resident layout, and ``wfa.solve`` routes through the
     implicit-function-theorem adjoint (:mod:`repro.solver.adjoint`).
 
     ``recovery=RecoveryPolicy(...)`` (:mod:`repro.solver.health`) arms the

@@ -93,8 +93,8 @@ class ExecutionPlan:
     layout: "HaloLayout" = None
     batch: int = 1  # leading ensemble axis every env buffer carries
     #: built for reverse-mode AD: runners must not donate entry buffers
-    #: (they become VJP residuals) and the plan skips the in-place
-    #: halo-resident layout — see RunOptions.differentiable
+    #: (they become VJP residuals) and the plan skips the halo-resident
+    #: layout — see RunOptions.differentiable
     differentiable: bool = False
 
     @property
@@ -138,8 +138,9 @@ def compile_body(
     ``resident=K`` (fused paths only) makes the step operate on the
     halo-resident layout of :mod:`repro.engine.layout`: env buffers carry a
     standing margin ``K >= time_tile·h``, refreshed in place per launch,
-    with kernel outputs aliased into the same buffers.  Interpreter steps
-    ignore it (the executor converts at segment boundaries).
+    with kernel outputs written to fresh buffers of the same extent.
+    Interpreter steps ignore it (the executor converts at segment
+    boundaries).
 
     ``batch=B`` builds an ensemble step over ``(B, ...)``-stacked env
     buffers: fused kernels are vmapped over the leading axis below the
@@ -385,8 +386,8 @@ def plan(
     Planning is two-pass so fields can be laid out *halo-resident*: pass one
     lowers every loop body and picks its tile factor, which fixes the
     run-wide margin ``K = max k·h``; pass two compiles each body against
-    that layout (margin refresh in place + aliased kernel outputs — see
-    :mod:`repro.engine.layout`).  ``resident=False`` forces the legacy
+    that layout (margin refresh in place + double-buffered kernel outputs —
+    see :mod:`repro.engine.layout`).  ``resident=False`` forces the legacy
     repack-per-launch steps (the bitwise reference the residency tests
     compare against).
     """
@@ -468,33 +469,29 @@ def plan(
             )
             log.warning("%s", reason)
         scheduled.append((loop, ops, group, k, reason, cost))
+    from repro.kernels.ops import _interpret
+
     pad = 0
     if resident and backend == "pallas" and not options.differentiable:
         # a differentiable plan keeps the repacking steps: the resident
-        # protocol's in-place aliased outputs and margin rewrites are
-        # exactly the buffer reuse a reverse pass cannot tolerate — saved
-        # residuals must survive the forward sweep
-        from repro.kernels.ops import _interpret
-
-        # In-place outputs are only safe where the kernel evaluates blocks
-        # functionally (interpret mode, this container's correctness path):
-        # on Mosaic the grid runs sequentially over an aliased HBM buffer,
-        # so a block's halo window would read the in-place outputs of the
-        # neighbouring blocks already executed in the same launch (a
-        # read-after-write Gauss–Seidel contamination).  Until the resident
-        # path double-buffers block outputs on TPU, Mosaic plans keep the
-        # legacy repacking steps — the same documented degradation rule as
-        # the multigrid transfer kernels (engine.plan_mg_levels).
+        # protocol's margin rewrites and donated buffers are exactly the
+        # buffer reuse a reverse pass cannot tolerate — saved residuals
+        # must survive the forward sweep
         pad = max(
             (k * g.halo for _, _, g, k, _, _ in scheduled if g is not None),
             default=0,
         )
-        if pad and not _interpret():
+        # Monolithic resident launches double-buffer (each reads one buffer
+        # and writes another), so they are safe on Mosaic, where the grid
+        # runs its blocks in sequence over HBM.  Mesh plans keep the
+        # repacking steps there until the margin exchange has been
+        # measured on a chip mesh.
+        if pad and mesh_ctx is not None and not _interpret():
             pad = 0
             stats.resident_dropped += 1
             _log_once(
-                "halo-resident layout off on Mosaic: fused launches repack "
-                "their inputs (and run no overlap split)"
+                "halo-resident layout off for mesh plans on Mosaic: fused "
+                "launches repack their inputs"
             )
     layout = HaloLayout(pad=pad, shapes=shapes)
 
@@ -507,9 +504,13 @@ def plan(
         # overlap decision: split the launch only where legal (resident
         # layout, nonempty interior at depth k·h) and wanted — forced by
         # overlap=True, or, on "auto", predicted faster by this body's
-        # calibrated cost-model entry (no entry → keep today's schedule)
+        # calibrated cost-model entry (no entry → keep today's schedule).
+        # The split's region launches write in place, which is safe only
+        # where blocks are evaluated functionally (interpret mode): on
+        # Mosaic a block's halo window would read rows an earlier block of
+        # the same launch already stepped.
         use_split = 0
-        if group is not None and pad > 0 and group.halo > 0:
+        if group is not None and pad > 0 and group.halo > 0 and _interpret():
             from repro.compiler.ir import split_regions
 
             sp = split_regions(group, k, _brick_xy(program, mesh_ctx, group))

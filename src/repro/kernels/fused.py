@@ -33,9 +33,18 @@ either way.  ``coords`` is a (1, 2) int32 array with the brick's global cell
 origin so one kernel image serves every brick — how one Worker image serves
 the whole WSE fabric.
 
+Halo-resident mode (``margin=``, the engine's
+:class:`~repro.engine.layout.HaloLayout`) takes inputs at the run-wide
+padded extent, their margins refreshed in place by the caller, and
+double-buffers: each launch reads one resident buffer and writes its
+written fields into another of the same extent, so the per-launch pad goes
+and the grid may run its blocks in sequence over HBM without reading a row
+it already stepped.  Only the overlap split's region launches write in
+place, and only in interpret mode.
+
 Reverse-mode AD never differentiates through this kernel: differentiable
-plans (``RunOptions(differentiable=True)``) keep donation and the in-place
-resident layout off, and ``engine.differentiable_runner`` wraps each launch
+plans (``RunOptions(differentiable=True)``) keep donation and the resident
+layout off, and ``engine.differentiable_runner`` wraps each launch
 in a ``custom_vjp`` whose backward replays the roll-interpreter reference —
 exact for the affine bodies the lowering pass admits, and indifferent to
 input aliasing because the primal kernel is only ever called on
@@ -211,13 +220,22 @@ def _apply_updates(updates, cur, h, out_x, out_y, gx0, gy0, nx, ny, wrap,
 
 
 def _fused_body(updates, in_names, written, h, k, wrap, bxb, ry, y_lo, nx,
-                ny, margin, interpret, coords_ref, *refs):
+                ny, margin, ywrap, interpret, coords_ref, *refs):
     kh = k * h
     in_refs = dict(zip(in_names, refs[:len(in_names)]))
     out_refs = dict(zip(written, refs[len(in_names):]))
     # the loaded windows span the array's whole Y extent; the body steps
     # the ry output rows of its region (plus their depth-kh halo)
-    cur = {n: r[:, y_lo:y_lo + ry + 2 * kh, :] for n, r in in_refs.items()}
+    if ywrap:
+        # each row's Y halo is the wrap of its own interior, built here so
+        # the caller refreshes only the X margin rows
+        cur = {n: jnp.concatenate([r[:, margin + ry - kh:margin + ry, :],
+                                   r[:, margin:margin + ry, :],
+                                   r[:, margin:margin + kh, :]], axis=1)
+               for n, r in in_refs.items()}
+    else:
+        cur = {n: r[:, y_lo:y_lo + ry + 2 * kh, :]
+               for n, r in in_refs.items()}
     i = pl.program_id(0)
     # global origin of the loaded window (halo depth k·h below the block)
     gx0 = coords_ref[0, 0] + i * bxb - kh
@@ -259,10 +277,17 @@ def build_fused_call(updates: Sequence, field_specs: Dict[str, Tuple[int, object
                       ``margin >= k·halo`` (the engine's
                       :class:`~repro.engine.layout.HaloLayout`), the kernel
                       reads its depth-``k·halo`` window from inside that
-                      margin, and every written field is emitted **in place**
-                      into its own input buffer via ``input_output_aliases``
-                      — outputs keep the resident extent and zero new
-                      buffers are allocated on the step path.
+                      margin, and every written field is emitted at the
+                      resident extent into a **separate** buffer (double
+                      buffering: no operand the launch reads is aliased, so
+                      the grid may run its blocks in sequence over HBM).
+                      The output's brick rows are written; its margin rows
+                      are left undefined — margins are transient and the
+                      caller refreshes them before any read.  With ``wrap``
+                      (one device) a monolithic launch reads no Y margin:
+                      it builds each loaded row's Y halo from the row's own
+                      interior, so the caller refreshes only the X margin
+                      rows (:func:`repro.engine.layout.wrap_refresh_rows`).
     ``region``      — a :class:`repro.compiler.ir.RegionSpec` *windowing*
                       the launch (resident mode only): the grid covers the
                       region's (rx, ry) output cells instead of the whole
@@ -272,13 +297,18 @@ def build_fused_call(updates: Sequence, field_specs: Dict[str, Tuple[int, object
                       the brick edge, so its input windows never touch the
                       margin frame and the launch needs no refreshed halo
                       data.  The caller must offset ``coords`` by the
-                      region origin so the Moat mask stays global.
+                      region origin so the Moat mask stays global.  A region
+                      launch writes **in place**: each written field aliases
+                      its input buffer via ``input_output_aliases``, which
+                      is safe only where blocks are evaluated functionally
+                      (interpret mode; the planner keeps the split off on
+                      Mosaic).
 
     Returns ``call(coords, *padded) -> tuple(new_fields)`` where ``padded``
     are the (bx + 2·k·halo, by + 2·k·halo, nz) inputs (resident extent when
     ``margin`` is set) in ``field_specs`` order and the outputs are the
     written fields, in first-written order — full (bx, by, nz) arrays, or
-    the updated resident buffers when ``margin`` is set.
+    resident-extent buffers when ``margin`` is set.
     """
     in_names = list(field_specs)
     written = []
@@ -318,9 +348,12 @@ def build_fused_call(updates: Sequence, field_specs: Dict[str, Tuple[int, object
     # Y offset of the region's depth-kh window inside the loaded window
     y_lo = margin - kh + oy if margin else 0
 
+    # a monolithic wrap launch on the resident layout builds its Y halo
+    # from the rows it loads (see _fused_body)
+    ywrap = bool(margin and wrap and region is None)
     body = functools.partial(_fused_body, tuple(updates), tuple(in_names),
                              tuple(written), h, k, wrap, bxb, ry, y_lo,
-                             nx, ny, margin, interpret)
+                             nx, ny, margin, ywrap, interpret)
     # window origin inside the input: the kernel always consumes a
     # (bxb + 2kh)-row window; with a resident margin that window sits
     # `margin - kh` rows inside the buffer edge (legacy inputs arrive
@@ -332,18 +365,22 @@ def build_fused_call(updates: Sequence, field_specs: Dict[str, Tuple[int, object
             (bxb + 2 * kh, ya, nz_of[name]),
             lambda i, ax=off_x: (ax + i * bxb, 0, 0)))
     if margin:
-        # in-place outputs: each written field aliases its own input buffer
-        # (full resident extent); the grid writes only the region's rows,
-        # and the body copies the pre-launch values of every cell it does
-        # not update, so margins (and, in region mode, the rest of the
-        # brick) keep their values.
+        # outputs at the resident extent; the grid writes only the region's
+        # rows, and the body copies the pre-launch values of every other
+        # cell of those rows.  A monolithic launch writes a fresh buffer:
+        # Mosaic runs the grid in sequence over HBM, so writing in place
+        # would let block i+1's halo window read rows block i already
+        # stepped.  Its margin rows are left unwritten (margins are
+        # transient).  A region launch aliases each written field's input
+        # buffer, so the rest of the brick keeps its values.
         out_specs = [element_block_spec(
             (bxb, ya, nz_of[n]),
             lambda i: (margin + ox + i * bxb, 0, 0)) for n in written]
         out_shape = [jax.ShapeDtypeStruct(
             (bx + 2 * margin, by + 2 * margin, nz_of[n]), field_specs[n][1])
             for n in written]
-        aliases = {1 + in_names.index(n): o for o, n in enumerate(written)}
+        aliases = ({} if region is None else
+                   {1 + in_names.index(n): o for o, n in enumerate(written)})
     else:
         out_specs = [pl.BlockSpec((bxb, by, nz_of[n]), lambda i: (i, 0, 0))
                      for n in written]

@@ -609,8 +609,10 @@ class SimulationService:
             # the explicit-path sentinel at the service's natural chunk
             # granule: one isfinite reduction per field per chunk (the
             # chunk runners donate, so the recovery state is the newest
-            # checkpoint, not a held env)
-            ok = bool(jax.device_get(ehealth.probe_ok_compiled(dict(env))))
+            # checkpoint, not a held env); a resident env is probed on its
+            # interiors, its margins being transient
+            pad = 0 if cw.mesh is not None else cw.layout.pad
+            ok = bool(jax.device_get(ehealth.probe_ok_compiled(dict(env), pad)))
             with self._slock:
                 estats.health_probes += 1
             if not ok:

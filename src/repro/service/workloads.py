@@ -45,7 +45,7 @@ import numpy as np
 from repro.core.field import Field
 from repro.core.program import ForLoop, scoped_program
 from repro.engine.plan import plan as build_plan
-from repro.engine.executor import fresh_buffer
+from repro.engine.executor import fresh_buffer, step_segment
 from repro.service.requests import PlanSignature
 
 Shape = Tuple[int, int, int]
@@ -286,9 +286,10 @@ class CompiledWorkload:
     def advance(self, m: int) -> Callable:
         """The jitted chunk runner for ``m`` logical steps (memoized).
 
-        Single device: steps the *resident padded* env in place (entry
-        donated — zero allocation in steady state).  Mesh: steps the global
-        unpadded env under ``shard_map`` (enter/exit per chunk, per brick).
+        Single device: steps the *resident padded* env (entry donated; the
+        launches double-buffer, so steady state holds two buffers a field).
+        Mesh: steps the global unpadded env under ``shard_map`` (enter/exit
+        per chunk, per brick).
         """
         with self._lock:
             hit = self._advance.get(m)
@@ -302,23 +303,11 @@ class CompiledWorkload:
             self._advance[m] = fn
             return fn
 
-    def _trace_chunk(self, env: dict, m: int) -> dict:
-        seg = self.segment
-        k = seg.time_tile if seg.kind == "fused" else 1
-        if k > 1:
-            env = jax.lax.fori_loop(0, m // k, lambda i, e: seg.step(e), env)
-            if m % k:
-                # the planner compiled step_rem because the workload's
-                # nominal trip count is k+1 (see build_workload)
-                env = jax.lax.fori_loop(
-                    0, m % k, lambda i, e: seg.step_rem(e), env
-                )
-            return env
-        return jax.lax.fori_loop(0, m, lambda i, e: seg.step(e), env)
-
     def _advance_single(self, m: int) -> Callable:
+        # the planner compiled step_rem because the workload's nominal trip
+        # count is k+1 (see build_workload)
         def run(env):
-            return self._trace_chunk(env, m)
+            return step_segment(self.segment, m, env)
 
         return jax.jit(run, donate_argnums=0)
 
@@ -334,7 +323,7 @@ class CompiledWorkload:
         layout = self.layout
 
         def local(env):
-            return layout.exit(self._trace_chunk(layout.enter(env), m))
+            return layout.exit(step_segment(self.segment, m, layout.enter(env)))
 
         return jax.jit(
             shard_map(

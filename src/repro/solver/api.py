@@ -419,13 +419,14 @@ def _build_step(
 
     ``resident=K`` compiles the application against the engine's
     halo-resident layout (standing margin-``K`` buffers, in-place refresh +
-    aliased outputs — :mod:`repro.engine.layout`).  The Krylov drivers keep
-    their vectors unpadded — each operator application is a single launch,
-    so the pad it saves is bought back by interior re-slicing in every dot
-    product — but the parameter keeps the solver on the same codegen
-    surface as the explicit executors; the solve-loop allocations are
-    instead eliminated by donating the jitted run's entry buffers
-    (``donate_argnums``) and XLA's in-place ``while_loop`` carries."""
+    double-buffered outputs — :mod:`repro.engine.layout`).  The Krylov
+    loops keep their vectors unpadded — each operator application is a
+    single launch, so the pad it saves is bought back by interior
+    re-slicing in every dot product — but the parameter keeps the solver
+    on the same codegen surface as the explicit executors; the solve-loop
+    allocations are instead eliminated by donating the jitted run's entry
+    buffers (``donate_argnums``) and XLA's in-place ``while_loop``
+    carries."""
     from repro.engine import compile_body
 
     if backend not in ("jit", "pallas"):

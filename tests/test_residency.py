@@ -23,7 +23,7 @@ import pytest
 from conftest import heat_init
 from repro.core import WSE_Array, WSE_For_Loop, WSE_Interface
 from repro.engine import HaloLayout, plan, reset_stats, single_runner, stats
-from repro.engine.layout import wrap_refresh
+from repro.engine.layout import wrap_refresh, wrap_refresh_rows
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -69,6 +69,19 @@ def build_advdiff(T0, steps):
     return wse, T
 
 
+def build_wide(T0, steps):
+    """Depth-2 taps in X and Y: cells next to the Moat read the wrapped
+    margins, so a stale margin changes the answer (the depth-1 bodies'
+    margins only ever feed Moat cells, which the mask keeps)."""
+    wse = WSE_Interface()
+    T = WSE_Array("T_w", init_data=T0)
+    with WSE_For_Loop("t", steps):
+        T[1:-1, 0, 0] = 0.5 * T[1:-1, 0, 0] + 0.1 * (
+            T[1:-1, 0, 2] + T[1:-1, 0, -2] + T[1:-1, 2, 0] + T[1:-1, -2, 0]
+        )
+    return wse, T
+
+
 # -- layout primitives --------------------------------------------------------
 
 
@@ -87,46 +100,114 @@ def test_layout_enter_exit_roundtrip_bitwise(rng):
     assert (np.asarray(lay0.exit(lay0.enter(env))["a"]) == env["a"]).all()
 
 
+@pytest.mark.parametrize("rows_only", [False, True])
 @pytest.mark.parametrize("K, h", [(1, 1), (3, 2), (3, 3)])
-def test_wrap_refresh_matches_jnp_pad_wrap(rng, K, h):
+def test_wrap_refresh_matches_jnp_pad_wrap(rng, K, h, rows_only):
+    """The four-slab refresh rebuilds the whole depth-h wrap frame; the
+    rows-only refresh (one device, whose kernel wraps Y itself) the rows
+    above and below the brick, over the interior columns."""
     x = rng.normal(size=(8, 6, 4)).astype(np.float32)
     lay = HaloLayout(pad=K, shapes={"x": x.shape})
-    resident = wrap_refresh(lay.enter({"x": x})["x"], K, h)
+    refresh = wrap_refresh_rows if rows_only else wrap_refresh
+    resident = refresh(lay.enter({"x": x})["x"], K, h)
     ref = jnp.pad(jnp.asarray(x), ((h, h), (h, h), (0, 0)), mode="wrap")
     lo = K - h
     window = resident[lo : lo + 8 + 2 * h, lo : lo + 6 + 2 * h, :]
+    if rows_only:
+        window, ref = window[:, h : h + 6], ref[:, h : h + 6]
     assert (np.asarray(window) == np.asarray(ref)).all()
 
 
 # -- resident stepping == repacking stepping (fp32, in-process) ---------------
+# Resident launches double-buffer and run in pairs per loop iteration, an odd
+# one after the loop (executor.run_launches): even and odd launch counts and
+# the n % k remainder take different paths through it.
 
 
-def test_resident_matches_repack_bitwise_heat():
+@pytest.mark.parametrize("steps", [6, 5])  # even, odd launch count
+def test_resident_matches_repack_bitwise_heat(steps):
     T0 = heat_init()
-    wse, T = build_heat(T0, 6)
+    wse, T = build_heat(T0, steps)
     res = wse.make(answer=T, backend="pallas").copy()
-    wse, T = build_heat(T0, 6)
+    wse, T = build_heat(T0, steps)
     leg = wse.make(answer=T, backend="pallas", resident=False).copy()
     assert (res == leg).all()
 
 
-def test_resident_matches_repack_bitwise_advdiff():
+@pytest.mark.parametrize("steps", [5, 6])  # odd, even launch count
+def test_resident_matches_repack_bitwise_advdiff(steps):
     rng = np.random.default_rng(3)
     T0 = rng.uniform(0.0, 1.0, size=(10, 9, 6)).astype(np.float32)
-    wse, T = build_advdiff(T0, 5)
+    wse, T = build_advdiff(T0, steps)
     res = wse.make(answer=T, backend="pallas").copy()
-    wse, T = build_advdiff(T0, 5)
+    wse, T = build_advdiff(T0, steps)
     leg = wse.make(answer=T, backend="pallas", resident=False).copy()
     assert (res == leg).all()
 
 
-def test_resident_matches_repack_bitwise_tiled_remainder():
-    T0 = heat_init()
-    wse, T = build_heat(T0, 7)
-    res = wse.make(answer=T, backend="pallas", time_tile=4).copy()
-    wse, T = build_heat(T0, 7)
-    leg = wse.make(answer=T, backend="pallas", time_tile=4, resident=False).copy()
+@pytest.mark.parametrize("steps, k", [(5, 1), (6, 2)])
+def test_resident_matches_repack_bitwise_wide_halo(steps, k):
+    rng = np.random.default_rng(5)
+    T0 = rng.uniform(0.0, 1.0, size=(10, 12, 6)).astype(np.float32)
+    wse, T = build_wide(T0, steps)
+    res = wse.make(answer=T, backend="pallas", time_tile=k).copy()
+    wse, T = build_wide(T0, steps)
+    leg = wse.make(answer=T, backend="pallas", time_tile=k,
+                   resident=False).copy()
     assert (res == leg).all()
+
+
+# (steps, k): tiled launches and remainder launches, each even or odd
+@pytest.mark.parametrize("steps, k", [(7, 4), (10, 4), (9, 2)])
+def test_resident_matches_repack_bitwise_tiled_remainder(steps, k):
+    T0 = heat_init()
+    wse, T = build_heat(T0, steps)
+    res = wse.make(answer=T, backend="pallas", time_tile=k).copy()
+    wse, T = build_heat(T0, steps)
+    leg = wse.make(answer=T, backend="pallas", time_tile=k,
+                   resident=False).copy()
+    assert (res == leg).all()
+
+
+def _legacy_answer(workload, shape, steps, k):
+    """The repacking run of a service workload's program, ``steps`` steps."""
+    from repro.engine import RunOptions, run_program
+    from repro.service.workloads import get_workload
+
+    program, answer = get_workload(workload).record(shape, np.float32, steps)
+    opts = RunOptions(backend="pallas", time_tile=k, resident=False)
+    return run_program(program, options=opts)[answer]
+
+
+@pytest.mark.parametrize("workload", ["heat3d", "advdiff"])
+@pytest.mark.parametrize("loop", ["guarded", "service"])
+def test_resident_step_loops_match_repack_bitwise(workload, loop):
+    """The guarded while-loop (``check_finite``) and the service's chunk
+    runner step through the same paired-launch helper as the executor:
+    both equal the repacking run bitwise, with odd chunks and a remainder."""
+    from repro.engine import RunOptions, run_program
+    from repro.service.requests import PlanSignature
+    from repro.service.workloads import build_workload, get_workload
+
+    shape, steps, k = (10, 12, 6), 9, 2
+    leg = _legacy_answer(workload, shape, steps, k)
+    reset_stats()
+    if loop == "guarded":
+        program, answer = get_workload(workload).record(shape, np.float32,
+                                                        steps)
+        # chunks of 3 launches (odd, inside the while-loop), then the
+        # remainder launch
+        opts = RunOptions(backend="pallas", time_tile=k, check_finite=6)
+        res = run_program(program, options=opts)[answer]
+        assert stats.resident_runs == 1 and stats.health_probes >= 2
+    else:
+        cw = build_workload(PlanSignature(workload, shape, time_tile=k))
+        assert cw.layout.pad == k
+        env = cw.initial_env(None)
+        for m in (6, 3):  # 3 tiled launches; 1 tiled + the remainder
+            env = cw.advance(m)(env)
+        res = cw.finalize(env)
+    assert (np.asarray(res) == leg).all()
 
 
 def test_resident_accounting_two_repacks_per_run():
@@ -187,6 +268,35 @@ def test_plan_layout_margin_is_max_tile_window():
     finally:
         wse.__exit__()
     assert p.layout.pad == 0  # interpreter plans never pad
+
+
+def test_plan_gate_on_mosaic_keeps_single_device_residency(monkeypatch):
+    """Planned as for Mosaic (``_interpret`` False; nothing is traced): a
+    single-device plan, batched or not, keeps the resident layout and
+    drops nothing, but runs no overlap split; a mesh plan still drops the
+    layout and counts it; a differentiable plan never asks for it."""
+    import repro.kernels.ops as kops
+    from repro.core.jaxcompat import make_mesh
+    from repro.engine import RunOptions
+
+    monkeypatch.setattr(kops, "_interpret", lambda: False)
+    T0 = heat_init((24, 24, 8))
+    wse, T = build_heat(T0, 8)
+    wse.__exit__()
+    reset_stats()
+    for opts in (RunOptions(backend="pallas", time_tile=2),
+                 RunOptions(backend="pallas", time_tile=2, batch=3),
+                 RunOptions(backend="pallas", time_tile=2, overlap=True)):
+        p = plan(wse.program, opts)
+        assert p.layout.pad == 2, opts
+        assert p.segments[0].kind == "fused" and p.segments[0].split == 0
+    assert stats.resident_dropped == 0
+    p = plan(wse.program, RunOptions(backend="pallas", differentiable=True))
+    assert p.layout.pad == 0 and stats.resident_dropped == 0
+    mesh = make_mesh((1, 1), ("data", "model"))
+    p = plan(wse.program, RunOptions(backend="pallas", mesh=mesh))
+    assert p.layout.pad == 0
+    assert stats.resident_dropped == 1
 
 
 # -- donation -----------------------------------------------------------------
@@ -282,17 +392,20 @@ A0 = rng.uniform(0.0, 1.0, size=(8, 12, 10))
 def test_fp64_resident_bitwise_single_device():
     out = run_py(BUILDERS + """
 for builder, T_init in [(build_heat, T0), (build_advdiff, A0)]:
-    wse, T = builder(T_init, 6, dtype=np.float64)
-    res = wse.make(answer=T, backend="pallas").copy()
-    assert res.dtype == np.float64, res.dtype
-    wse, T = builder(T_init, 6, dtype=np.float64)
-    leg = wse.make(answer=T, backend="pallas", resident=False).copy()
-    assert (res == leg).all(), builder
-wse, T = build_heat(T0, 8, dtype=np.float64)
-rk = wse.make(answer=T, backend="pallas", time_tile=4).copy()
-wse, T = build_heat(T0, 8, dtype=np.float64)
-lk = wse.make(answer=T, backend="pallas", time_tile=4, resident=False).copy()
-assert (rk == lk).all()
+    for steps in (6, 5):  # even, odd launch count
+        wse, T = builder(T_init, steps, dtype=np.float64)
+        res = wse.make(answer=T, backend="pallas").copy()
+        assert res.dtype == np.float64, res.dtype
+        wse, T = builder(T_init, steps, dtype=np.float64)
+        leg = wse.make(answer=T, backend="pallas", resident=False).copy()
+        assert (res == leg).all(), (builder, steps)
+for steps in (8, 11):  # no remainder; an odd remainder of 3
+    wse, T = build_heat(T0, steps, dtype=np.float64)
+    rk = wse.make(answer=T, backend="pallas", time_tile=4).copy()
+    wse, T = build_heat(T0, steps, dtype=np.float64)
+    lk = wse.make(answer=T, backend="pallas", time_tile=4,
+                  resident=False).copy()
+    assert (rk == lk).all(), steps
 print("OK")
 """, x64=True)
     assert "OK" in out

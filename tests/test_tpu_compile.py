@@ -113,6 +113,36 @@ def test_heat_resident_kernel_compiles(one_chip):
     _compile(step, {"T": x})
 
 
+def test_heat_resident_runner_double_buffers(one_chip, monkeypatch):
+    """The single-device resident runner at 512x512x128 as the engine
+    builds it for Mosaic (64 steps, auto tile): its kernel aliases no
+    operand it reads, and its step loop holds no copy of a resident-extent
+    array (launches run in pairs, so the two buffers trade places)."""
+    import repro.kernels.ops as kops
+    from repro.engine import RunOptions, plan
+    from repro.engine.executor import _trace_plan
+    from repro.service.workloads import _record_heat3d
+
+    monkeypatch.setattr(kops, "_interpret", lambda: False)
+    program, _ = _record_heat3d(SHAPE, np.float32, 64)
+    p = plan(program, RunOptions(backend="pallas"))
+    K = p.layout.pad
+    assert K > 0 and p.segments[0].kind == "fused"
+    env = {n: jax.ShapeDtypeStruct(f.shape, f.dtype, sharding=one_chip)
+           for n, f in program.fields.items()}
+    text = jax.jit(lambda e: _trace_plan(p, e), donate_argnums=0).lower(
+        env).compile().as_text()
+    kernels = [l for l in text.splitlines() if "tpu_custom_call" in l
+               and "custom-call(" in l]
+    assert kernels and all("output_to_operand_aliasing" not in l
+                           for l in kernels), kernels
+    resident = f"f32[{SHAPE[0] + 2 * K},{SHAPE[1] + 2 * K},{SHAPE[2]}]"
+    assert " while(" in text
+    copies = [l for l in text.splitlines()
+              if re.search(r"= \S+ copy(-start)?\(", l) and resident in l]
+    assert not copies, copies
+
+
 def test_heat_sharded_step_compiles(topo):
     """One fused step inside shard_map on a 2x2 mesh: 256x256x128 bricks,
     halo exchange by ppermute."""

@@ -17,6 +17,7 @@ or 512.
 """
 from __future__ import annotations
 
+import threading
 from typing import Dict
 
 import jax
@@ -27,9 +28,41 @@ from repro.core import stencil as st
 from repro.core.jaxcompat import make_mesh
 from repro.core.program import Program
 
+#: device scope of the neighbour transfers (and the slabs they send)
+EXCHANGE = "wfa.halo.exchange"
+#: device scope of :func:`halo_pad`'s whole-brick pad, the repack
+PAD = "wfa.halo.pad"
+
+#: per thread, one running total per open :func:`counted` trace
+_sent = threading.local()
+
+
+def counted(step, members: int = 1):
+    """``step``, which notes each time it is traced the bytes one chip sends
+    by ``ppermute`` in one application, as ``halo_bytes`` (the mean over the
+    chips: an edge brick sends nothing outward, so on a 2x2 mesh every chip
+    sends one X slab and one Y slab of each field).  ``members`` is the
+    ensemble width a ``vmap`` inside ``step`` hides from the traced shapes.
+    """
+
+    def run(env):
+        totals = _sent.__dict__.setdefault("totals", [])
+        totals.append(0.0)
+        try:
+            return step(env)
+        finally:
+            run.halo_bytes = totals.pop() * members
+
+    run.halo_bytes = 0.0
+    return run
+
 
 def _ppermute_shift(x, axis_name: str, n: int, direction: int):
     """Receive neighbour data from ``direction`` (+1: from lower index)."""
+    totals = getattr(_sent, "totals", None)
+    if totals:
+        # n - 1 of the axis' n chips send
+        totals[-1] += x.size * x.dtype.itemsize * (n - 1) / n
     if direction > 0:
         perm = [(i, i + 1) for i in range(n - 1)]
     else:
@@ -45,16 +78,20 @@ def halo_pad(local, h: int, ax_x: str, ax_y: str, mx: int, my: int):
     *inside* the edge bricks (the Moat), matching the paper's layout.
     Leading (batch) axes pass through: a ``(B, bx, by, Z)`` ensemble brick
     moves all B members' halo planes in the same ``ppermute``.
+
+    One zero pad of the whole brick (the repack, scope ``wfa.halo.pad``),
+    then the four margin slabs exchanged and landed in it
+    (:func:`halo_refresh`), so this is bitwise what the halo-resident path
+    steps on.  Not two concatenates: XLA rewrites a concatenate inside the
+    step loop into in-place writes to a fresh buffer, one of them a second
+    copy of the whole brick that carries no scope.
     """
     if h == 0:
         return local
-    # X axis: receive the high plane of the -x neighbour, low plane of +x.
-    lo_x = _ppermute_shift(local[..., -h:, :, :], ax_x, mx, +1)
-    hi_x = _ppermute_shift(local[..., :h, :, :], ax_x, mx, -1)
-    local = jnp.concatenate([lo_x, local, hi_x], axis=-3)
-    lo_y = _ppermute_shift(local[..., -h:, :], ax_y, my, +1)
-    hi_y = _ppermute_shift(local[..., :h, :], ax_y, my, -1)
-    return jnp.concatenate([lo_y, local, hi_y], axis=-2)
+    widths = ((0, 0),) * (local.ndim - 3) + ((h, h), (h, h), (0, 0))
+    with jax.named_scope(PAD):
+        padded = jnp.pad(local, widths)
+    return halo_refresh(padded, h, h, ax_x, ax_y, mx, my)
 
 
 def exchange_slabs(resident, margin: int, h: int, ax_x: str, ax_y: str,
@@ -65,8 +102,7 @@ def exchange_slabs(resident, margin: int, h: int, ax_x: str, ax_y: str,
     ``ppermute`` edge transfers per axis, the Y transfers sourced from the
     x-extended rows (own edge columns flanked by the incoming X slabs'
     corner pieces), so corner cells arrive from the diagonal neighbour in
-    two fabric hops — bitwise what :func:`halo_pad`'s concatenates build,
-    zero fill on domain-edge bricks included.  The slabs stay in their own
+    two fabric hops, zero fill on domain-edge bricks included.  The slabs stay in their own
     small arrays until :func:`repro.engine.layout.land_slabs` stores them:
     the returned dict is the *in-flight exchange* the overlap scheduler
     launches the interior kernel alongside, never aliasing the resident
@@ -75,25 +111,26 @@ def exchange_slabs(resident, margin: int, h: int, ax_x: str, ax_y: str,
     K = margin
     bx = resident.shape[-3] - 2 * K
     by = resident.shape[-2] - 2 * K
-    # X axis: slabs of the interior's edge rows (full interior Y extent).
-    lo_x = _ppermute_shift(resident[..., K + bx - h:K + bx, K:K + by, :],
-                           ax_x, mx, +1)
-    hi_x = _ppermute_shift(resident[..., K:K + h, K:K + by, :], ax_x, mx, -1)
-    # Y axis: sources span the x-extended rows (corner pieces from the X
-    # slabs just received), exactly like halo_pad's second concat.
-    src_lo = jnp.concatenate([
-        lo_x[..., :, by - h:by, :],
-        resident[..., K:K + bx, K + by - h:K + by, :],
-        hi_x[..., :, by - h:by, :],
-    ], axis=-3)
-    src_hi = jnp.concatenate([
-        lo_x[..., :, 0:h, :],
-        resident[..., K:K + bx, K:K + h, :],
-        hi_x[..., :, 0:h, :],
-    ], axis=-3)
-    lo_y = _ppermute_shift(src_lo, ax_y, my, +1)
-    hi_y = _ppermute_shift(src_hi, ax_y, my, -1)
-    return {"lo_x": lo_x, "hi_x": hi_x, "lo_y": lo_y, "hi_y": hi_y}
+    with jax.named_scope(EXCHANGE):
+        # X axis: slabs of the interior's edge rows (full interior Y extent).
+        lo_x = _ppermute_shift(resident[..., K + bx - h:K + bx, K:K + by, :],
+                               ax_x, mx, +1)
+        hi_x = _ppermute_shift(resident[..., K:K + h, K:K + by, :], ax_x, mx, -1)
+        # Y axis: sources span the x-extended rows (corner pieces from the X
+        # slabs just received).
+        src_lo = jnp.concatenate([
+            lo_x[..., :, by - h:by, :],
+            resident[..., K:K + bx, K + by - h:K + by, :],
+            hi_x[..., :, by - h:by, :],
+        ], axis=-3)
+        src_hi = jnp.concatenate([
+            lo_x[..., :, 0:h, :],
+            resident[..., K:K + bx, K:K + h, :],
+            hi_x[..., :, 0:h, :],
+        ], axis=-3)
+        lo_y = _ppermute_shift(src_lo, ax_y, my, +1)
+        hi_y = _ppermute_shift(src_hi, ax_y, my, -1)
+        return {"lo_x": lo_x, "hi_x": hi_x, "lo_y": lo_y, "hi_y": hi_y}
 
 
 def halo_refresh(resident, margin: int, h: int, ax_x: str, ax_y: str,
@@ -102,12 +139,12 @@ def halo_refresh(resident, margin: int, h: int, ax_x: str, ax_y: str,
 
     ``resident`` is a (bx + 2·margin, by + 2·margin, Z) buffer whose interior
     holds the brick (see :class:`repro.engine.layout.HaloLayout`).  Instead
-    of rebuilding a padded copy per step (:func:`halo_pad`'s concatenate),
+    of rebuilding a padded copy per step (:func:`halo_pad`'s repack),
     only the four margin *slabs* move (:func:`exchange_slabs`), each written
     back with ``dynamic_update_slice`` — the narrow in-place update that
-    keeps fields resident while halos travel.  The slab contents (including
-    corners, and the zero fill on domain-edge bricks) are bitwise identical
-    to what :func:`halo_pad` would have produced, so resident and repacking
+    keeps fields resident while halos travel.  :func:`halo_pad` lands the
+    same slabs (corners, and the zero fill on domain-edge bricks,
+    included) in its freshly padded brick, so resident and repacking
     execution agree exactly.  Leading (batch) axes pass through — one slab
     transfer refreshes every ensemble member.
     """
@@ -116,7 +153,8 @@ def halo_refresh(resident, margin: int, h: int, ax_x: str, ax_y: str,
     from repro.engine.layout import land_slabs
 
     slabs = exchange_slabs(resident, margin, h, ax_x, ax_y, mx, my)
-    return land_slabs(resident, slabs, margin, h)
+    with jax.named_scope("wfa.engine.margin_refresh"):
+        return land_slabs(resident, slabs, margin, h)
 
 
 def local_moat_mask(bx: int, by: int, ax_x: str, ax_y: str, mx: int, my: int):

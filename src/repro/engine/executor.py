@@ -172,6 +172,12 @@ def _account(plan: ExecutionPlan) -> None:
             if plan.mesh is not None:
                 stats.exchanges += n * len(seg.ops)
                 stats.repacks += n * len(seg.ops)
+        if plan.mesh is not None:
+            # noted on the mesh steps when they were traced
+            stats.halo_bytes += (
+                n // k * getattr(seg.step, "halo_bytes", 0.0)
+                + n % k * getattr(seg.step_rem, "halo_bytes", 0.0)
+            )
 
 
 def _run_numpy(plan: ExecutionPlan, env: Dict[str, np.ndarray], check: int = 0):
@@ -247,8 +253,15 @@ def single_runner(plan: ExecutionPlan):
         return _trace_plan(plan, env)
 
     donate = () if plan.differentiable else (0,)
-    jitted = jax.jit(run, donate_argnums=donate)
-    record_program(jitted, (_arg_spec(plan),))
+    return _dispatching(plan, jax.jit(run, donate_argnums=donate), _arg_spec(plan))
+
+
+def _dispatching(plan: ExecutionPlan, jitted, spec):
+    """The runner around ``jitted``: each call one ``wfa.engine.dispatch``
+    span that adds the plan's counts to :data:`repro.engine.stats`, with
+    ``lower``; the program is recorded, on the env ``spec``, for
+    :func:`repro.engine.stats.device_scopes`."""
+    record_program(jitted, (spec,))
 
     def runner(env):
         with span("wfa.engine.dispatch"):
@@ -270,7 +283,12 @@ def sharded_runner(plan: ExecutionPlan, names=None):
 
     Returns ``(runner, sharding)``; the layout enter/exit happens *inside*
     the mapped function, so resident buffers are per-brick and the margin
-    refresh is pure neighbour ppermute.
+    refresh is pure neighbour ppermute.  As with :func:`single_runner`,
+    each call is one ``wfa.engine.dispatch`` span and adds the plan's
+    counts (``halo_bytes`` among them) to :data:`repro.engine.stats`, the
+    jitted program is recorded for
+    :func:`repro.engine.stats.device_scopes`, and ``runner.lower`` lowers
+    it.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -291,14 +309,18 @@ def sharded_runner(plan: ExecutionPlan, names=None):
         shard_map(local, mesh=mesh, in_specs=(specs,), out_specs=specs, check=False),
         donate_argnums=() if plan.differentiable else (0,),
     )
-    return stepped, sharding
+    arg = _arg_spec(plan)
+    shapes = {
+        k: jax.ShapeDtypeStruct(arg[k].shape, arg[k].dtype, sharding=sharding)
+        for k in specs
+    }
+    return _dispatching(plan, stepped, shapes), sharding
 
 
 def _run_sharded(plan: ExecutionPlan, env):
-    stepped, sharding = sharded_runner(plan, names=list(env))
+    runner, sharding = sharded_runner(plan, names=list(env))
     genv = {k: jax.device_put(fresh_buffer(v), sharding) for k, v in env.items()}
-    out = stepped(genv)
-    _account(plan)
+    out = runner(genv)
     return {k: np.asarray(jax.device_get(v)) for k, v in out.items()}
 
 

@@ -4,8 +4,8 @@ The WFA's two-orders-of-magnitude win comes from keeping every field
 resident in PE-local memory for the whole run — only halo cells travel
 (Rocki et al., arXiv:2010.03660).  The engine's analogue is this module:
 instead of rebuilding a padded copy of every field per kernel launch
-(``jnp.pad(mode="wrap")`` on one device, ``halo_pad``'s concatenates under
-``shard_map``), each stenciled field is stored **once** at its run-wide
+(``jnp.pad(mode="wrap")`` on one device, ``halo_pad``'s zero pad and slab
+landing under ``shard_map``), each stenciled field is stored **once** at its run-wide
 padded extent ``(nx + 2K, ny + 2K, nz)``, where ``K`` is the largest halo
 window any scheduled segment needs (``max k·h`` over the plan, computed at
 :func:`repro.engine.plan.plan` time).

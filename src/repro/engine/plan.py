@@ -28,6 +28,7 @@ from typing import Callable, List, Optional, Tuple
 
 from repro.compiler import LoweringError, auto_tile, lower_group, tile_group
 from repro.compiler.codegen import compile_group, compile_group_sharded, try_compile
+from repro.core.halo import counted
 from repro.core.program import Program, _group_ops, _interp_step
 from repro.engine.options import UNSET, RunOptions, resolve_options
 from repro.engine.stats import stats
@@ -131,9 +132,10 @@ def compile_body(
     fallback on :class:`LoweringError` counted in ``repro.compiler.stats``);
     ``backend="jit"`` returns the shared roll-interpreter step.  With
     ``mesh_ctx`` the step operates on per-device bricks inside ``shard_map``
-    (ppermute halo exchange); without, on the global array.  Explicit
-    program execution, ``run_sharded`` and the solver's operator/rhs
-    applications all obtain their steps here.
+    (ppermute halo exchange, the bytes one chip sends a step noted as the
+    step's ``halo_bytes`` — :func:`repro.core.halo.counted`); without, on
+    the global array.  Explicit program execution, ``run_sharded`` and the
+    solver's operator/rhs applications all obtain their steps here.
 
     ``resident=K`` (fused paths only) makes the step operate on the
     halo-resident layout of :mod:`repro.engine.layout`: env buffers carry a
@@ -197,7 +199,7 @@ def compile_body(
 
         step = try_compile(fn, loop)
         if step is not None:
-            return step, True
+            return (step if mesh_ctx is None else counted(step)), True
     elif backend != "jit":
         raise ValueError(f"unknown backend {backend!r}; expected one of {BACKENDS}")
     if mesh_ctx is None:
@@ -207,11 +209,12 @@ def compile_body(
 
         mx, my, ax_x, ax_y = mesh_ctx
         base = interp_step_sharded(ops, ax_x, ax_y, mx, my)
+    step = base
     if batch > 1:
         import jax
 
-        return (lambda env: jax.vmap(base)(dict(env))), False
-    return base, False
+        step = lambda env: jax.vmap(base)(dict(env))  # noqa: E731
+    return (step if mesh_ctx is None else counted(step, batch)), False
 
 
 @dataclasses.dataclass

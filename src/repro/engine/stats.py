@@ -1,8 +1,9 @@
 """Execution counters, host spans and device scopes for the unified engine.
 
 One global :data:`stats` instance (mirroring ``repro.compiler.stats``) that
-:func:`repro.engine.plan`, the runner of :func:`repro.engine.single_runner`
-(once per dispatch) and :func:`repro.engine.execute` update in place;
+:func:`repro.engine.plan`, the runners of :func:`repro.engine.single_runner`
+and :func:`repro.engine.sharded_runner` (once per dispatch) and
+:func:`repro.engine.execute` update in place;
 tests and benchmarks ``reset_stats()`` around a run and assert on the
 communication accounting — the headline being :attr:`EngineStats.
 exchanges_per_step`, which temporal blocking must drop k×.
@@ -15,7 +16,10 @@ Spans and scopes name the program's own work, ``wfa.<layer>.<what>``:
   (``time.perf_counter_ns``), read with :func:`spans`;
 * ``jax.named_scope`` names device work inside the jitted programs
   (``wfa.engine.wrap_pad``, ``.margin_refresh``, ``.layout``,
-  ``wfa.kernel.stencil``, ``wfa.krylov.dot``, ``.update``); the runners
+  ``wfa.kernel.stencil``, ``wfa.krylov.dot``, ``.update``, and on a mesh
+  ``wfa.halo.exchange`` — the ``ppermute`` transfers and the slabs they
+  send — and ``wfa.halo.pad``, the whole-brick pad that repacks a brick
+  for its halo); the runners
   :func:`record_program` what they compile, and :func:`device_scopes`
   maps each compiled instruction to its innermost scope, so a device
   trace's operations can be split by the code that issued them.
@@ -62,6 +66,9 @@ class EngineStats:
     steps_run: int = 0  # logical time steps executed
     launches: int = 0  # kernel / interpreter-step invocations
     exchanges: int = 0  # halo exchanges, wrap pads or margin refreshes
+    #: bytes one chip sends by ppermute (the mean over a mesh's chips),
+    #: from the slab shapes of each traced mesh step times its launches
+    halo_bytes: float = 0.0
     tiles_fused: int = 0  # k>1 tiled launches (k steps per launch)
     resident_runs: int = 0  # executions stepping on a halo-resident layout
     #: full-field pad/copy conversions: one per fused launch on the legacy

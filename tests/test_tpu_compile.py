@@ -190,3 +190,55 @@ def test_mg_smoother_compiles(one_chip):
     step = compile_group(ops, shapes, dtypes, interpret=False)
     x = jax.ShapeDtypeStruct((n, n, n), jnp.float32, sharding=one_chip)
     _compile(step, {"x": x, "b": x})
+
+
+def test_heat_mesh_runner_compiles_at_the_deployment_size(topo, monkeypatch):
+    """The ``heat3d.mesh2x2`` runner as the engine builds it for Mosaic:
+    1024x1024x128 over the 2x2 mesh (512x512x128 bricks), 64 steps, auto
+    tile, on the repacking path; its loop holds the kernel and the
+    collective-permutes, named by the halo's scopes, and it fits a chip."""
+    import repro.kernels.ops as kops
+    from repro.engine import RunOptions, plan, sharded_runner
+    from repro.service.workloads import _record_heat3d
+
+    monkeypatch.setattr(kops, "_interpret", lambda: False)
+    shape = (2 * SHAPE[0], 2 * SHAPE[1], SHAPE[2])
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("x", "y"))
+    program, _ = _record_heat3d(shape, np.float32, 64)
+    p = plan(program, RunOptions(backend="pallas", mesh=mesh))
+    assert p.layout.pad == 0 and p.segments[0].kind == "fused"
+    run, sharding = sharded_runner(p)
+    env = {n: jax.ShapeDtypeStruct(f.shape, f.dtype, sharding=sharding)
+           for n, f in program.fields.items()}
+    compiled = run.lower(env).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "collective-permute" in text
+    assert "wfa.halo.exchange" in text and "wfa.halo.pad" in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 2**30
+    assert _unscoped_whole_arrays(text) == []
+
+
+def _unscoped_whole_arrays(text):
+    """Operations a device trace shows (those outside fused computations)
+    that write 2**20 elements or more and carry no ``wfa.*`` scope: a
+    trace could not name their time."""
+    from repro.engine.stats import instruction_key
+
+    skip = {"parameter", "get-tuple-element", "tuple", "bitcast", "constant",
+            "while", "conditional", "call"}
+    fused, out = False, []
+    for line in text.splitlines():
+        if line and not line[0].isspace():
+            fused = "fused" in line.split(" ")[0]
+            continue
+        key = instruction_key(line)
+        if fused or key is None:
+            continue
+        opcode = re.search(r"\s([a-z][a-z0-9\-]*)\(", line.split("=", 1)[1]).group(1)
+        sizes = [np.prod([int(d) for d in s[s.index("[") + 1:-1].split(",") if d])
+                 for s in key[1]]
+        if (opcode not in skip and max(sizes, default=0) >= 2**20
+                and not re.search(r'op_name="[^"]*wfa\.', line)):
+            out.append(key)
+    return out

@@ -206,3 +206,73 @@ def test_span_ring_agrees_with_the_profiler_trace(tmp_path):
     assert len(work) == len(mine) == 5
     for (ts, te), (rs, re_) in zip(work, mine):
         assert abs(ts - rs) < 50e3 and abs(te - re_) < 50e3
+
+
+MESH_CHILD = r'''
+import json
+import jax, jax.numpy as jnp
+from repro.core.jaxcompat import make_mesh
+from repro.engine import (RunOptions, device_scopes, plan, reset_stats,
+                          sharded_runner, spans, stats)
+from repro.engine.stats import _programs, instruction_key
+from repro.service.workloads import _record_heat3d
+
+mesh = make_mesh((2, 2), ("x", "y"))
+out = {}
+for resident in (False, True):
+    program, _ = _record_heat3d((32, 32, 8), jnp.float32, 4)
+    p = plan(program, RunOptions(backend="pallas", mesh=mesh, time_tile=2,
+                                 resident=resident))
+    before = list(_programs)
+    run, sharding = sharded_runner(p)
+    (new,) = [f for f in _programs if all(f is not b for b in before)]
+    env = {n: jax.device_put(jnp.ones(f.shape, f.dtype), sharding)
+           for n, f in program.fields.items()}
+    reset_stats()
+    jax.block_until_ready(run(env))
+    text = new.lower(*_programs[new]).compile().as_text()
+    scopes = device_scopes()
+    keys = map(instruction_key, text.splitlines())
+    mapped = sorted({scopes[k] for k in keys if k in scopes})
+    out["resident" if resident else "repack"] = {
+        "spans": [[n, parent] for n, _, _, parent in spans()],
+        "mapped": mapped, "halo_bytes": stats.halo_bytes,
+        "launches": stats.launches, "pad": p.layout.pad}
+print("RESULT " + json.dumps(out))
+'''
+
+
+@pytest.fixture(scope="module")
+def mesh_runs():
+    """One child process on four virtual CPU devices (the test process
+    keeps one) runs a 2x2 mesh runner on each layout."""
+    import json
+    import subprocess
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               JAX_PLATFORMS="cpu", PYTHONPATH=os.path.join(root, "src"))
+    p = subprocess.run([sys.executable, "-c", MESH_CHILD], capture_output=True,
+                       text=True, timeout=600, env=env)
+    assert p.returncode == 0, p.stderr[-4000:]
+    (line,) = [s for s in p.stdout.splitlines() if s.startswith("RESULT ")]
+    return json.loads(line[len("RESULT "):])
+
+
+@pytest.mark.parametrize("layout, expected", [
+    ("repack", {"wfa.halo.exchange", "wfa.halo.pad", "wfa.kernel.stencil"}),
+    ("resident", {"wfa.halo.exchange", "wfa.engine.margin_refresh",
+                  "wfa.engine.layout", "wfa.kernel.stencil"}),
+])
+def test_sharded_runner_spans_scopes_and_halo_bytes(mesh_runs, layout, expected):
+    """A call of ``sharded_runner``'s runner is one ``wfa.engine.dispatch``
+    span; its program is recorded, and ``device_scopes`` maps its
+    instructions to the halo's scopes; ``halo_bytes`` moves by one X slab
+    (2, 16, 8) and one Y slab (20, 2, 8) of float32 a launch (k=2, 16x16x8
+    bricks, two launches)."""
+    r = mesh_runs[layout]
+    assert r["spans"] == [["wfa.engine.dispatch", None]]
+    assert expected <= set(r["mapped"]), r["mapped"]
+    assert (r["pad"] > 0) == (layout == "resident")
+    assert r["launches"] == 2
+    assert r["halo_bytes"] == 2 * 4 * (2 * 16 * 8 + 20 * 2 * 8)

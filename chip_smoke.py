@@ -20,7 +20,9 @@ One chip, ``HeatConfig()`` (512x512x128 fp32, 3.3e7 cells):
       retries, no degraded requests and no kernel built after warm-up.
 
 ``--chips 4`` runs only heat3d (64 explicit steps and a CG solve) on a 2x2
-mesh of four chips, each against the same run on one chip.
+mesh of four chips, each against the same run on one chip; the explicit
+mesh run steps the halo-resident layout and must equal the mesh's
+repacking steps (``resident=False``) bit for bit.
 
 Times are informational.  Any failed check raises, so the exit status is
 non-zero; the last line of stdout is one JSON object, printed only when
@@ -167,7 +169,6 @@ def _counters() -> dict:
         "launches": estats.launches,
         "exchanges_per_step": estats.exchanges_per_step,
         "resident_runs": estats.resident_runs,
-        "resident_dropped": estats.resident_dropped,
     }
 
 
@@ -305,7 +306,8 @@ def phase_service(cfg: HeatConfig, n_step: int = 4, steps: int = 16) -> dict:
 
 
 def phase_mesh(cfg: HeatConfig, devices, steps: int = STEPS) -> dict:
-    """``--chips 4``: heat3d explicit and CG on a 2x2 mesh vs one device."""
+    """``--chips 4``: heat3d explicit and CG on a 2x2 mesh vs one device,
+    and the explicit mesh run's resident layout vs its repacking steps."""
     from repro.core.jaxcompat import make_mesh
 
     require(len(devices) == 4, f"need 4 devices, have {len(devices)}")
@@ -315,13 +317,22 @@ def phase_mesh(cfg: HeatConfig, devices, steps: int = STEPS) -> dict:
 
     wse, T = fig3_program(cfg, steps)
     single = wfa.make(wse, T, options=one)
+    _reset_counters()
     wse, T = fig3_program(cfg, steps)
     t0 = time.perf_counter()
     sharded = wfa.make(wse, T, options=four)
     make_s = time.perf_counter() - t0
+    resident_runs = _counters()["resident_runs"]
     err = rel_err(sharded, single)
     require(np.all(np.isfinite(sharded)), "sharded explicit result not finite")
     require(err <= RTOL, f"sharded explicit rel err {err:.3e} > {RTOL:g}")
+    require(resident_runs == 1, "the mesh run did not step the resident layout")
+    # the repacking steps (a whole-brick pad a launch) are the reference the
+    # halo-resident layout must equal bit for bit
+    wse, T = fig3_program(cfg, steps)
+    repacked = wfa.make(wse, T, options=four.replace(resident=False))
+    differ = int(np.sum(sharded != repacked))
+    require(differ == 0, f"resident and repacking mesh runs differ in {differ} cells")
 
     kw = dict(method="cg", tol=cfg.tol, maxiter=cfg.maxiter, return_info=True)
     wse, T = record_implicit(cfg)
@@ -337,6 +348,8 @@ def phase_mesh(cfg: HeatConfig, devices, steps: int = STEPS) -> dict:
     return {
         "brick": (cfg.nx // 2, cfg.ny // 2, cfg.nz),
         "explicit_rel_err": err,
+        "resident_runs": resident_runs,
+        "resident_vs_repack_cells_differ": differ,
         "solve_rel_err": serr,
         "iterations_1": int(i1.iterations[0]),
         "iterations_4": int(i4.iterations[0]),

@@ -16,9 +16,10 @@ Two integration points:
   semantics bit-for-bit (wrap-around only ever lands in Moat cells for
   depth-1 stencils; for wider stencils the backends still agree because both
   wrap).
-* :func:`compile_group_sharded` — inside ``shard_map``.  The brick is
-  halo-padded with ``core.halo.halo_pad`` (ICI ppermute) and the kernel's
-  Moat mask is driven by the brick's mesh coordinates.
+* :func:`compile_group_sharded` — inside ``shard_map``.  The brick's
+  margin slabs cross by ICI ppermute (landed in a resident buffer, or in
+  a ``core.halo.halo_pad`` repack) and the kernel's Moat mask is driven by
+  the brick's mesh coordinates.
 """
 from __future__ import annotations
 
@@ -372,10 +373,11 @@ def compile_group_sharded(ops, shapes: Dict[str, tuple],
 
     ``resident=K`` switches to the halo-resident protocol: the brick env
     holds ``(bx + 2K, by + 2K, nz)`` buffers, the exchange moves only the
-    four depth-``k·h`` margin slabs (:func:`repro.core.halo.halo_refresh` —
-    same ppermute traffic, no concatenated repack) and the kernel writes
-    double-buffered resident outputs, as on one device.  Bitwise identical
-    to the repacking step at every precision.
+    four depth-``k·h`` margin slabs (same ppermute traffic, no whole-brick
+    repack), the two X slabs land in place and the two Y slabs go to the
+    kernel as operands (:func:`repro.core.halo.halo_refresh_rows`), and the
+    kernel writes double-buffered resident outputs, as on one device.
+    Bitwise identical to the repacking step at every precision.
 
     ``overlap=True`` (resident mode only) splits each launch into an
     interior kernel — concurrent with the margin slabs' ``ppermute``
@@ -384,7 +386,7 @@ def compile_group_sharded(ops, shapes: Dict[str, tuple],
     too small for a nonempty interior keep the monolithic launch.
     """
     from repro.compiler.ir import split_regions, tile_group
-    from repro.core.halo import exchange_slabs, halo_pad, halo_refresh
+    from repro.core.halo import exchange_slabs, halo_pad, halo_refresh_rows
 
     if group is None:
         group = lower_group(ops)
@@ -436,11 +438,17 @@ def compile_group_sharded(ops, shapes: Dict[str, tuple],
         def step(env):
             env = dict(env)
             coords = _coords()
-            ins = [halo_refresh(env[n], resident, ph, ax_x, ax_y, mx, my)
-                   for n in in_names]
-            ins = list(jax.lax.optimization_barrier(tuple(ins)))
-            outs = _call(coords, ins)
-            for name, inp in zip(in_names, ins):
+            ins, yslabs = [], []
+            for n in in_names:
+                buf = env[n]
+                if ph:  # a halo-free body exchanges nothing
+                    buf, lo_y, hi_y = halo_refresh_rows(buf, resident, ph,
+                                                        ax_x, ax_y, mx, my)
+                    yslabs += [lo_y, hi_y]
+                ins.append(buf)
+            pinned = jax.lax.optimization_barrier(tuple(ins) + tuple(yslabs))
+            outs = _call(coords, list(pinned))
+            for name, inp in zip(in_names, pinned[:len(ins)]):
                 env[name] = inp
             for name, out in zip(written, outs):
                 env[name] = out

@@ -9,11 +9,11 @@ direct analogue of the WSE's W→C→E / N→C→S background threads.  Time-til
 segments exchange depth ``k·h`` once per k steps (temporal blocking — the
 engine's communication amortization).
 
-This module owns the mesh-level primitives (``halo_pad``, the traced Moat
-mask, the sharded roll-interpreter step); scheduling and backend dispatch
-live in :mod:`repro.engine`.  ``run_sharded`` is the thin mesh entry point
-into that engine, so the paper's Fig. 3 script runs unchanged on 1 device
-or 512.
+This module owns the mesh-level primitives (``halo_pad``,
+``halo_refresh_rows``, the traced Moat mask, the sharded roll-interpreter
+step); scheduling and backend dispatch live in :mod:`repro.engine`.
+``run_sharded`` is the thin mesh entry point into that engine, so the
+paper's Fig. 3 script runs unchanged on 1 device or 512.
 """
 from __future__ import annotations
 
@@ -80,18 +80,23 @@ def halo_pad(local, h: int, ax_x: str, ax_y: str, mx: int, my: int):
     moves all B members' halo planes in the same ``ppermute``.
 
     One zero pad of the whole brick (the repack, scope ``wfa.halo.pad``),
-    then the four margin slabs exchanged and landed in it
-    (:func:`halo_refresh`), so this is bitwise what the halo-resident path
-    steps on.  Not two concatenates: XLA rewrites a concatenate inside the
-    step loop into in-place writes to a fresh buffer, one of them a second
-    copy of the whole brick that carries no scope.
+    then the four margin slabs exchanged (:func:`exchange_slabs`) and
+    landed in it, so the kernel's window is bitwise the one the
+    halo-resident path builds (:func:`halo_refresh_rows`).  Not two
+    concatenates: XLA rewrites a concatenate inside the step loop into
+    in-place writes to a fresh buffer, one of them a second copy of the
+    whole brick that carries no scope.
     """
     if h == 0:
         return local
+    from repro.engine.layout import land_slabs
+
     widths = ((0, 0),) * (local.ndim - 3) + ((h, h), (h, h), (0, 0))
     with jax.named_scope(PAD):
         padded = jnp.pad(local, widths)
-    return halo_refresh(padded, h, h, ax_x, ax_y, mx, my)
+    slabs = exchange_slabs(padded, h, h, ax_x, ax_y, mx, my)
+    with jax.named_scope("wfa.engine.margin_refresh"):
+        return land_slabs(padded, slabs, h, h)
 
 
 def exchange_slabs(resident, margin: int, h: int, ax_x: str, ax_y: str,
@@ -133,28 +138,27 @@ def exchange_slabs(resident, margin: int, h: int, ax_x: str, ax_y: str,
         return {"lo_x": lo_x, "hi_x": hi_x, "lo_y": lo_y, "hi_y": hi_y}
 
 
-def halo_refresh(resident, margin: int, h: int, ax_x: str, ax_y: str,
-                 mx: int, my: int):
-    """Refresh the depth-``h`` margin of a halo-*resident* brick in place.
+def halo_refresh_rows(resident, margin: int, h: int, ax_x: str, ax_y: str,
+                      mx: int, my: int):
+    """Exchange the four depth-``h`` margin slabs, land only the two X slabs.
 
-    ``resident`` is a (bx + 2·margin, by + 2·margin, Z) buffer whose interior
-    holds the brick (see :class:`repro.engine.layout.HaloLayout`).  Instead
-    of rebuilding a padded copy per step (:func:`halo_pad`'s repack),
-    only the four margin *slabs* move (:func:`exchange_slabs`), each written
-    back with ``dynamic_update_slice`` — the narrow in-place update that
-    keeps fields resident while halos travel.  :func:`halo_pad` lands the
-    same slabs (corners, and the zero fill on domain-edge bricks,
-    included) in its freshly padded brick, so resident and repacking
-    execution agree exactly.  Leading (batch) axes pass through — one slab
-    transfer refreshes every ensemble member.
+    The mesh step's refresh: its monolithic kernel takes the received
+    ``lo_y`` / ``hi_y`` slabs as operands and builds each loaded row's Y
+    halo from them (see :func:`repro.kernels.fused.build_fused_call`), so
+    only the rows above and below the brick are written in place — two
+    runs of whole rows, where the Y slabs would cost a narrow write into
+    every row.  Returns ``(resident, lo_y, hi_y)``: the same ``ppermute``
+    traffic as :func:`halo_pad`, and the kernel sees the same window.
+    Leading (batch) axes pass through — one slab transfer refreshes every
+    ensemble member.
     """
-    if h == 0:
-        return resident
     from repro.engine.layout import land_slabs
 
     slabs = exchange_slabs(resident, margin, h, ax_x, ax_y, mx, my)
+    rows = {name: slabs[name] for name in ("lo_x", "hi_x")}
     with jax.named_scope("wfa.engine.margin_refresh"):
-        return land_slabs(resident, slabs, margin, h)
+        resident = land_slabs(resident, rows, margin, h)
+    return resident, slabs["lo_y"], slabs["hi_y"]
 
 
 def local_moat_mask(bx: int, by: int, ax_x: str, ax_y: str, mx: int, my: int):
@@ -239,7 +243,7 @@ def run_sharded(program: Program, env: Dict[str, np.ndarray], mesh=None,
     ``shard_map``.  Bodies that cannot be lowered fall back to
     :func:`interp_step_sharded` with a logged reason.  Fused bricks step
     halo-resident (standing padded brick buffers, margin-slab ppermute
-    refresh via :func:`halo_refresh`, donated entry buffers);
+    refresh via :func:`halo_refresh_rows`, donated entry buffers);
     ``resident=False`` forces the legacy repacking steps — both are bitwise
     identical.
 

@@ -15,10 +15,11 @@ Execution then touches memory three ways, none of which repacks a field:
 * **enter/exit** — one conversion at each *program boundary* (start and end
   of one ``execute``), never inside the step loop;
 * **margin refresh** — before a kernel launch reads a depth-``ph`` window,
-  only edge *slabs* are rewritten in place: on a mesh the four
-  :func:`repro.core.halo.halo_refresh` ``ppermute`` slabs; on one device
-  the two X slabs (:func:`wrap_refresh_rows`), since the kernel builds
-  each loaded row's Y halo from the row's own interior;
+  only the two X *slabs* are rewritten in place, since the kernel builds
+  each loaded row's Y halo itself: on one device from the row's own
+  interior (:func:`wrap_refresh_rows`), on a mesh from the two received Y
+  slabs it takes as operands (:func:`repro.core.halo.halo_refresh_rows`,
+  which exchanges all four by ``ppermute``);
 * **double-buffered outputs** — a fused launch reads one resident buffer
   and writes each written field into a second buffer of the same extent
   (see :func:`repro.kernels.fused.build_fused_call`): the kernel's grid may
@@ -179,7 +180,7 @@ def land_slabs(resident, slabs: Dict[str, jnp.ndarray], margin: int, h: int):
 def wrap_refresh(resident, margin: int, h: int):
     """Refresh the depth-``h`` wrap margin of a resident array in place.
 
-    The single-device analogue of :func:`repro.core.halo.halo_refresh`:
+    The single-device analogue of :func:`repro.core.halo.halo_pad`'s landing:
     reproduces exactly what ``jnp.pad(interior, h, mode="wrap")`` would have
     built — the periodic margins the roll interpreter's semantics demand —
     but as four ``dynamic_update_slice`` edge slabs into the standing buffer

@@ -480,22 +480,13 @@ def plan(
         # protocol's margin rewrites and donated buffers are exactly the
         # buffer reuse a reverse pass cannot tolerate — saved residuals
         # must survive the forward sweep
+        # monolithic resident launches double-buffer (each reads one buffer
+        # and writes another), so they are safe on Mosaic, where the grid
+        # runs its blocks in sequence over HBM, on one device and on a mesh
         pad = max(
             (k * g.halo for _, _, g, k, _, _ in scheduled if g is not None),
             default=0,
         )
-        # Monolithic resident launches double-buffer (each reads one buffer
-        # and writes another), so they are safe on Mosaic, where the grid
-        # runs its blocks in sequence over HBM.  Mesh plans keep the
-        # repacking steps there until the margin exchange has been
-        # measured on a chip mesh.
-        if pad and mesh_ctx is not None and not _interpret():
-            pad = 0
-            stats.resident_dropped += 1
-            _log_once(
-                "halo-resident layout off for mesh plans on Mosaic: fused "
-                "launches repack their inputs"
-            )
     layout = HaloLayout(pad=pad, shapes=shapes)
 
     # pass two: compile each body against the layout

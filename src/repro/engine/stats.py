@@ -75,9 +75,6 @@ class EngineStats:
     #: path; on a resident run only the layout enter/exit events (2 for an
     #: all-fused plan, +2 around each interpreter segment in a mixed plan)
     repacks: int = 0
-    #: mesh plans that kept the repacking steps on Mosaic instead of the
-    #: halo-resident layout (see :func:`repro.engine.plan`)
-    resident_dropped: int = 0
     max_time_tile: int = 1  # largest k any segment ran with
     tile_reasons: Tuple[str, ...] = ()  # why a tile factor was clamped/refused
 

@@ -220,19 +220,29 @@ def _apply_updates(updates, cur, h, out_x, out_y, gx0, gy0, nx, ny, wrap,
 
 
 def _fused_body(updates, in_names, written, h, k, wrap, bxb, ry, y_lo, nx,
-                ny, margin, ywrap, interpret, coords_ref, *refs):
+                ny, margin, yhalo, interpret, coords_ref, *refs):
     kh = k * h
-    in_refs = dict(zip(in_names, refs[:len(in_names)]))
-    out_refs = dict(zip(written, refs[len(in_names):]))
+    n_in = len(in_names)
+    in_refs = dict(zip(in_names, refs[:n_in]))
+    n_slabs = 2 * n_in if yhalo == "slabs" else 0
+    slabs = refs[n_in:n_in + n_slabs]   # lo_y, hi_y of each input field
+    out_refs = dict(zip(written, refs[n_in + n_slabs:]))
     # the loaded windows span the array's whole Y extent; the body steps
     # the ry output rows of its region (plus their depth-kh halo)
-    if ywrap:
+    if yhalo == "wrap":
         # each row's Y halo is the wrap of its own interior, built here so
         # the caller refreshes only the X margin rows
         cur = {n: jnp.concatenate([r[:, margin + ry - kh:margin + ry, :],
                                    r[:, margin:margin + ry, :],
                                    r[:, margin:margin + kh, :]], axis=1)
                for n, r in in_refs.items()}
+    elif yhalo == "slabs":
+        # each row's Y halo comes from the neighbours' received Y slabs,
+        # operands of the launch, so the caller lands only the X slabs
+        cur = {n: jnp.concatenate([slabs[2 * j][...],
+                                   r[:, margin:margin + ry, :],
+                                   slabs[2 * j + 1][...]], axis=1)
+               for j, (n, r) in enumerate(in_refs.items())}
     else:
         cur = {n: r[:, y_lo:y_lo + ry + 2 * kh, :]
                for n, r in in_refs.items()}
@@ -283,11 +293,15 @@ def build_fused_call(updates: Sequence, field_specs: Dict[str, Tuple[int, object
                       the grid may run its blocks in sequence over HBM).
                       The output's brick rows are written; its margin rows
                       are left undefined — margins are transient and the
-                      caller refreshes them before any read.  With ``wrap``
-                      (one device) a monolithic launch reads no Y margin:
+                      caller refreshes them before any read.  A monolithic
+                      launch reads no Y margin: with ``wrap`` (one device)
                       it builds each loaded row's Y halo from the row's own
-                      interior, so the caller refreshes only the X margin
-                      rows (:func:`repro.engine.layout.wrap_refresh_rows`).
+                      interior (:func:`repro.engine.layout.wrap_refresh_rows`),
+                      and on a mesh (``halo > 0``) from the neighbours'
+                      ``lo_y`` / ``hi_y`` slabs, ``(bx + 2·k·halo, k·halo,
+                      nz)`` operands that follow the inputs, two per field
+                      (:func:`repro.core.halo.halo_refresh_rows`), so the
+                      caller lands only the X margin rows.
     ``region``      — a :class:`repro.compiler.ir.RegionSpec` *windowing*
                       the launch (resident mode only): the grid covers the
                       region's (rx, ry) output cells instead of the whole
@@ -306,7 +320,9 @@ def build_fused_call(updates: Sequence, field_specs: Dict[str, Tuple[int, object
 
     Returns ``call(coords, *padded) -> tuple(new_fields)`` where ``padded``
     are the (bx + 2·k·halo, by + 2·k·halo, nz) inputs (resident extent when
-    ``margin`` is set) in ``field_specs`` order and the outputs are the
+    ``margin`` is set) in ``field_specs`` order, followed on a monolithic
+    mesh launch at the resident extent by each field's ``lo_y`` and
+    ``hi_y`` slabs, and the outputs are the
     written fields, in first-written order — full (bx, by, nz) arrays, or
     resident-extent buffers when ``margin`` is set.
     """
@@ -348,12 +364,15 @@ def build_fused_call(updates: Sequence, field_specs: Dict[str, Tuple[int, object
     # Y offset of the region's depth-kh window inside the loaded window
     y_lo = margin - kh + oy if margin else 0
 
-    # a monolithic wrap launch on the resident layout builds its Y halo
-    # from the rows it loads (see _fused_body)
-    ywrap = bool(margin and wrap and region is None)
+    # a monolithic launch on the resident layout reads no Y margin: it
+    # builds each loaded row's Y halo from the row itself (wrap) or from
+    # the received Y slabs (mesh; see _fused_body)
+    yhalo = None
+    if margin and kh and region is None:
+        yhalo = "wrap" if wrap else "slabs"
     body = functools.partial(_fused_body, tuple(updates), tuple(in_names),
                              tuple(written), h, k, wrap, bxb, ry, y_lo,
-                             nx, ny, margin, ywrap, interpret)
+                             nx, ny, margin, yhalo, interpret)
     # window origin inside the input: the kernel always consumes a
     # (bxb + 2kh)-row window; with a resident margin that window sits
     # `margin - kh` rows inside the buffer edge (legacy inputs arrive
@@ -364,6 +383,13 @@ def build_fused_call(updates: Sequence, field_specs: Dict[str, Tuple[int, object
         in_specs.append(element_block_spec(
             (bxb + 2 * kh, ya, nz_of[name]),
             lambda i, ax=off_x: (ax + i * bxb, 0, 0)))
+    if yhalo == "slabs":
+        # the (bx + 2kh, kh, nz) lo_y / hi_y slabs span the x-extended rows,
+        # so a block's window starts at the same row in slab coordinates
+        for name in in_names:
+            in_specs += [element_block_spec(
+                (bxb + 2 * kh, kh, nz_of[name]),
+                lambda i: (i * bxb, 0, 0))] * 2
     if margin:
         # outputs at the resident extent; the grid writes only the region's
         # rows, and the body copies the pre-launch values of every other

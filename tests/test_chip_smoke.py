@@ -49,7 +49,7 @@ def test_explicit_phase_at_smoke_size():
     assert out["segment"] == "fused" and out["fallbacks"] == 0
     # k=4 at 64 steps on a 16x16 brick (4·k·h ≤ 16), halo-resident here
     assert out["time_tile"] == 4 and out["resident_layout"]
-    assert out["resident_dropped"] == 0
+    assert out["resident_runs"] == 1
 
 
 def test_solve_phase_at_smoke_size():
@@ -72,7 +72,7 @@ def test_service_phase_at_smoke_size():
 
 
 def test_mesh_phase_on_four_cpu_devices():
-    """``--chips 4``'s comparison on four virtual CPU devices."""
+    """``--chips 4``'s comparisons on four virtual CPU devices."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = str(ROOT / "src")
@@ -85,3 +85,6 @@ def test_mesh_phase_on_four_cpu_devices():
                          text=True, timeout=600, env=env, cwd=ROOT)
     assert out.returncode == 0, out.stderr[-4000:]
     assert '"solve_rel_err"' in out.stdout
+    # the mesh stepped resident and equalled its repacking steps bitwise
+    assert '"resident_runs": 1' in out.stdout
+    assert '"resident_vs_repack_cells_differ": 0' in out.stdout

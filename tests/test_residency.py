@@ -271,10 +271,10 @@ def test_plan_layout_margin_is_max_tile_window():
 
 
 def test_plan_gate_on_mosaic_keeps_single_device_residency(monkeypatch):
-    """Planned as for Mosaic (``_interpret`` False; nothing is traced): a
-    single-device plan, batched or not, keeps the resident layout and
-    drops nothing, but runs no overlap split; a mesh plan still drops the
-    layout and counts it; a differentiable plan never asks for it."""
+    """Planned as for Mosaic (``_interpret`` False; nothing is traced):
+    single-device and mesh plans, batched or not, keep the resident layout
+    but run no overlap split, whose region launches write in place; a
+    differentiable plan, on one device or a mesh, never asks for it."""
     import repro.kernels.ops as kops
     from repro.core.jaxcompat import make_mesh
     from repro.engine import RunOptions
@@ -283,20 +283,16 @@ def test_plan_gate_on_mosaic_keeps_single_device_residency(monkeypatch):
     T0 = heat_init((24, 24, 8))
     wse, T = build_heat(T0, 8)
     wse.__exit__()
-    reset_stats()
-    for opts in (RunOptions(backend="pallas", time_tile=2),
-                 RunOptions(backend="pallas", time_tile=2, batch=3),
-                 RunOptions(backend="pallas", time_tile=2, overlap=True)):
-        p = plan(wse.program, opts)
-        assert p.layout.pad == 2, opts
-        assert p.segments[0].kind == "fused" and p.segments[0].split == 0
-    assert stats.resident_dropped == 0
-    p = plan(wse.program, RunOptions(backend="pallas", differentiable=True))
-    assert p.layout.pad == 0 and stats.resident_dropped == 0
     mesh = make_mesh((1, 1), ("data", "model"))
-    p = plan(wse.program, RunOptions(backend="pallas", mesh=mesh))
-    assert p.layout.pad == 0
-    assert stats.resident_dropped == 1
+    for base in (RunOptions(backend="pallas", time_tile=2),
+                 RunOptions(backend="pallas", time_tile=2, mesh=mesh)):
+        for opts in (base, base.replace(batch=3), base.replace(overlap=True)):
+            p = plan(wse.program, opts)
+            assert p.layout.pad == 2, opts
+            assert p.segments[0].kind == "fused", opts
+            assert p.segments[0].split == 0, opts
+        p = plan(wse.program, base.replace(differentiable=True))
+        assert p.layout.pad == 0, base
 
 
 # -- donation -----------------------------------------------------------------
@@ -437,4 +433,61 @@ for builder, T_init, name in [(build_heat, T0, "T_n"),
     assert (res == single).all(), name
 print("OK")
 """, devices=4, x64=True)
+    assert "OK" in out
+
+
+def test_resident_bitwise_sharded_tiled_and_batched():
+    """On a 2x2 mesh at k=2 (even and odd trip counts, so the untiled
+    remainder launch reads a margin deeper than its window), for a batched
+    ensemble, and for a plan whose second body is halo-free: the resident
+    layout, whose kernel takes the received Y slabs as operands, equals the
+    repacking steps bitwise, and each ensemble member equals its own run."""
+    out = run_py(BUILDERS + """
+import repro as wfa
+from repro.core.jaxcompat import make_mesh
+from repro.engine import RunOptions, reset_stats, stats
+
+def member(T0, steps):
+    wse, T = build_heat(T0, steps)
+    wse.__exit__()
+    return wse, T
+
+mesh = make_mesh((2, 2), ("data", "model"))
+rng = np.random.default_rng(5)
+inits = [rng.uniform(250.0, 550.0, (16, 16, 8)).astype(np.float32)
+         for _ in range(2)]
+opts = RunOptions(backend="pallas", mesh=mesh, time_tile=2)
+for steps in (8, 9):
+    wse, T = member(inits[0], steps)
+    reset_stats()
+    res = wse.make(answer=T, options=opts).copy()
+    assert stats.resident_runs == 1, vars(stats)
+    wse, T = member(inits[0], steps)
+    leg = wse.make(answer=T, options=opts.replace(resident=False)).copy()
+    assert (res == leg).all(), steps
+ens = lambda: wfa.Ensemble.from_programs([member(T0, 9) for T0 in inits])
+reset_stats()
+out = ens().make(options=opts)
+assert stats.resident_runs == 1 and stats.ensemble_runs == 1, vars(stats)
+assert (out == ens().make(options=opts.replace(resident=False))).all()
+for b, T0 in enumerate(inits):
+    wse, T = member(T0, 9)
+    assert (out[b] == wse.make(answer=T, options=opts)).all(), b
+
+def halo_and_halo_free(T0):
+    # the halo-free body steps at the plan's margin and exchanges nothing
+    wse, T = build_heat(T0, 3)
+    with WSE_For_Loop("s", 2):
+        T[1:-1, 0, 0] = 0.5 * T[1:-1, 0, 0]
+    wse.__exit__()
+    return wse, T
+
+mixed = []
+for resident in (True, False):
+    wse, T = halo_and_halo_free(inits[0])
+    mixed.append(wse.make(answer=T, options=RunOptions(
+        backend="pallas", mesh=mesh, resident=resident)).copy())
+assert (mixed[0] == mixed[1]).all()
+print("OK")
+""", devices=4)
     assert "OK" in out

@@ -195,8 +195,11 @@ def test_mg_smoother_compiles(one_chip):
 def test_heat_mesh_runner_compiles_at_the_deployment_size(topo, monkeypatch):
     """The ``heat3d.mesh2x2`` runner as the engine builds it for Mosaic:
     1024x1024x128 over the 2x2 mesh (512x512x128 bricks), 64 steps, auto
-    tile, on the repacking path; its loop holds the kernel and the
-    collective-permutes, named by the halo's scopes, and it fits a chip."""
+    tile, on the halo-resident layout.  As on one chip, its kernel aliases
+    no operand it reads and its loop copies no resident-extent array (the
+    two buffers trade places); no brick is repacked (no ``wfa.halo.pad``);
+    the collective-permutes carry the exchange's scope; every whole-array
+    operation is named; and it fits a chip."""
     import repro.kernels.ops as kops
     from repro.engine import RunOptions, plan, sharded_runner
     from repro.service.workloads import _record_heat3d
@@ -206,14 +209,26 @@ def test_heat_mesh_runner_compiles_at_the_deployment_size(topo, monkeypatch):
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("x", "y"))
     program, _ = _record_heat3d(shape, np.float32, 64)
     p = plan(program, RunOptions(backend="pallas", mesh=mesh))
-    assert p.layout.pad == 0 and p.segments[0].kind == "fused"
+    K = p.layout.pad
+    assert K > 0 and p.segments[0].kind == "fused"
     run, sharding = sharded_runner(p)
     env = {n: jax.ShapeDtypeStruct(f.shape, f.dtype, sharding=sharding)
            for n, f in program.fields.items()}
     compiled = run.lower(env).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text and "collective-permute" in text
-    assert "wfa.halo.exchange" in text and "wfa.halo.pad" in text
+    kernels = [l for l in text.splitlines() if "tpu_custom_call" in l
+               and "custom-call(" in l]
+    assert kernels and all("output_to_operand_aliasing" not in l
+                           for l in kernels), kernels
+    resident = f"f32[{SHAPE[0] + 2 * K},{SHAPE[1] + 2 * K},{SHAPE[2]}]"
+    assert " while(" in text
+    copies = [l for l in text.splitlines()
+              if re.search(r"= \S+ copy(-start)?\(", l) and resident in l]
+    assert not copies, copies
+    assert "wfa.halo.pad" not in text
+    permutes = [l for l in text.splitlines()
+                if re.search(r"\bcollective-permute(-start)?\(", l)]
+    assert permutes and all("wfa.halo.exchange" in l for l in permutes)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 2**30
     assert _unscoped_whole_arrays(text) == []
